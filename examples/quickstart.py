@@ -48,9 +48,9 @@ print(report.summary())
 assert report.verified
 
 print("\n=== 2. inject a missing all-reduce and catch it ===")
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 
-mesh = abstract_mesh((TP,), ("model",))
+mesh = AbstractMesh((TP,), ("model",))
 gb, b_in, _ = trace(baseline, *avals, name="base")
 gd, d_in, _ = trace_sharded(distributed, mesh, specs, P(), *avals)
 bug = drop_all_reduce(gd, index=1)

@@ -60,9 +60,9 @@ def trace_lint_unit(arch: str, tp: int = 1, *, sp: bool = False,
     single-device graphs still get the full IR family of lints (and the
     sharding family trivially passes — everything is replicated)."""
     import jax
+    from jax.sharding import AbstractMesh
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import abstract_mesh
     from repro.core.trace import trace, trace_sharded
     from repro.models import Model
     from repro.parallel.ctx import ParallelCtx
@@ -94,7 +94,7 @@ def trace_lint_unit(arch: str, tp: int = 1, *, sp: bool = False,
             output_placements=["dup"] * len(g.outputs),
             arch=arch, trace_s=time.perf_counter() - t0)
 
-    mesh = abstract_mesh((tp,), (TP_AXIS,))
+    mesh = AbstractMesh((tp,), (TP_AXIS,))
     pctx = ParallelCtx(tp_axis=TP_AXIS, tp_size=tp, ep_axis=TP_AXIS,
                        ep_size=tp, sp=sp)
     _, model_d, param_shapes = model_pair(cfg, pctx)

@@ -120,15 +120,14 @@ def _vp_embed_template_ok(prop, nids, table_shape, ids_shape, dtype,
     if key not in _vp_embed_templates:
         import jax
         import jax.numpy as jnp
+        from jax.sharding import AbstractMesh
         from jax.sharding import PartitionSpec as P
-
-        from repro.compat import abstract_mesh
 
         from repro.parallel.collectives import vp_embed, vp_embed_partial
 
         from ..trace import trace_sharded
 
-        mesh = abstract_mesh((prop.size,), (prop.axis,))
+        mesh = AbstractMesh((prop.size,), (prop.axis,))
         tbl = jax.ShapeDtypeStruct((table_shape[0] * prop.size, table_shape[1]),
                                    dtype)
         idv = jax.ShapeDtypeStruct(tuple(ids_shape), jnp.int32)

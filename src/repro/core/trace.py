@@ -19,7 +19,6 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import numpy as np
-from jax import core as jcore  # noqa: F401  (kept for forward-compat pins)
 
 from .ir import Graph
 
@@ -292,6 +291,18 @@ class Tracer:
                     },
                 )
             )
+        elif prim == "split":
+            # split (e.g. the transpose of concatenate) == one slice per piece
+            axis, off = params["axis"], 0
+            shape = eqn.invars[0].aval.shape
+            for i, size in enumerate(params["sizes"]):
+                start = [0] * len(shape)
+                limit = list(shape)
+                start[axis], limit[axis] = off, off + size
+                off += size
+                outs.append(add("slice", {"start_indices": tuple(start),
+                                          "limit_indices": tuple(limit),
+                                          "strides": None}, which_out=i))
         elif prim == "concatenate":
             outs.append(add("concat", {"dimension": params["dimension"]}))
         elif prim in ("psum", "pmax", "pmin", "all_gather", "reduce_scatter",
@@ -497,7 +508,7 @@ def trace_sharded(
     """Trace the **per-device** program of ``shard_map(fn)`` (collectives
     explicit).  ``avals`` are *global* shapes; input nodes carry per-shard
     shapes as seen by the device program."""
-    from repro.compat import shard_map
+    from jax import shard_map
 
     sm = shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                    check_vma=check_vma)

@@ -19,8 +19,6 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import AbstractMesh, PartitionSpec
 
-from repro.compat import abstract_mesh
-
 from .ir import ELEMENTWISE, Graph, LEAF_OPS
 from .partition import PartitionedVerifier, TemplateCache
 from .relations import DUP, PARTIAL, SHARD, Diagnostic, RelStore
@@ -415,7 +413,7 @@ def verify_sharded(
     """
     from repro.verify.specs import spec_input_facts
 
-    mesh = mesh or abstract_mesh((size,), (axis,))
+    mesh = mesh or AbstractMesh((size,), (axis,))
     options = options or VerifyOptions(axis=axis)
     gb, b_in, _b_out = trace(base_fn, *avals, name="base")
     gd, d_in, _d_out = trace_sharded(

@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture x input-shape x mesh) cell: build the SPMD step
@@ -9,8 +5,9 @@ function (shard_map with explicit collectives), ``.lower().compile()`` it for
 the production mesh, and record memory_analysis / cost_analysis / collective
 wire bytes into a JSON artifact consumed by EXPERIMENTS.md §Dry-run/§Roofline.
 
-The host-platform device-count override above MUST precede every other
-import — jax locks the device count on first init.  Never set it globally.
+The 512-device mesh is virtual: ``main`` pins JAX to the CPU platform with
+512 host devices before any backend starts, also on a machine with a TPU.
+``--all`` spawns one child process per cell and starts no backend itself.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen3_4b --shape train_4k
@@ -28,9 +25,9 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs import SHAPES, get_config, input_specs, skip_reason
 from repro.configs.base import ARCH_IDS
 from repro.launch import analysis
@@ -345,6 +342,10 @@ def main(argv=None):
         print("failures:", failures)
         sys.exit(1 if failures else 0)
 
+    # the production mesh is virtual: 512 CPU devices, set before any
+    # backend starts (also on a machine with a TPU)
+    jax.config.update("jax_platforms", "cpu")
+    jax.config.update("jax_num_cpu_devices", 512)
     res = run_cell(args.arch, args.shape, multi_pod=args.multi_pod, zero1=args.zero1,
                    sp=args.sp, micro=args.micro, compress=args.compress,
                    gather_weights=args.gather_weights, pure_dp=args.pure_dp,

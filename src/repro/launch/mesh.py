@@ -1,9 +1,9 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
-never touches jax device state.  The dry-run sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import; tests and benches see the real single CPU device.
+never touches jax device state.  The dry-run's ``main`` pins its process
+to 512 virtual CPU devices before any backend starts; tests and benches
+see the real devices.
 """
 from __future__ import annotations
 

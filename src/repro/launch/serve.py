@@ -6,7 +6,10 @@ to the single-device decode step before the engine starts.
 
 Usage (CPU demo):
   python -m repro.launch.serve --arch qwen3_4b --smoke --requests 4 --max-new 8 \
-      --verify-tp 4
+      --verify-tp 2
+
+Usage (TPU, full published width):
+  python -m repro.launch.serve --arch qwen3_4b --slots 1 --max-len 512
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.base import ARCH_IDS
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import Model
 from repro.serve import Engine, ServeConfig
 
@@ -26,7 +30,8 @@ from repro.serve import Engine, ServeConfig
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen3_4b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction, default=False,
+                    help="reduced config (default: the full published config)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--max-len", type=int, default=64)
@@ -37,6 +42,7 @@ def main(argv=None) -> int:
                          "degree before serving (0 = skip)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.encoder_only:
         print(f"{args.arch} is encoder-only: no decode serving")
@@ -62,7 +68,7 @@ def main(argv=None) -> int:
                   "semantically equivalent")
             return 2
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    params = jax.jit(model.init)(jax.random.PRNGKey(args.seed))
     eng = Engine(model, params, ServeConfig(max_len=args.max_len,
                                             batch_slots=args.slots))
     rng = np.random.default_rng(args.seed)

@@ -10,6 +10,9 @@ Flow (the paper's technique as a first-class framework feature):
 Usage (CPU demo, any arch):
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python -m repro.launch.train --arch qwen3_4b --smoke --steps 50 --tp 2 --dp 4
+
+Usage (TPU, full width, Pallas kernels compiled by Mosaic):
+  python -m repro.launch.train --arch mamba2_130m --impl pallas --steps 10
 """
 from __future__ import annotations
 
@@ -19,11 +22,15 @@ import time
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.configs.base import ARCH_IDS
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models import Model
 from repro.parallel.ctx import ParallelCtx
@@ -31,6 +38,21 @@ from repro.parallel.sharding import batch_spec, param_specs
 from repro.train import checkpoint as ckpt
 from repro.train.optimizer import AdamWConfig, adamw_init
 from repro.train.trainer import TrainConfig, make_step_fn
+
+
+def init_state(model: Model, mesh, key):
+    """Params and AdamW state created directly in their mesh shardings (from
+    ``param_specs``): no device ever holds an unsharded copy.  Returns
+    ``(params, opt, shardings)``, ``shardings`` being the matching pair of
+    NamedSharding pytrees."""
+    pspecs = param_specs(jax.eval_shape(model.init, key))
+    ospecs = {"m": pspecs, "v": pspecs, "step": P()}
+    named = lambda specs: jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), specs)
+    shardings = (named(pspecs), named(ospecs))
+    params = jax.jit(model.init, out_shardings=shardings[0])(key)
+    opt = jax.jit(adamw_init, out_shardings=shardings[1])(params)
+    return params, opt, shardings
 
 
 def main(argv=None) -> int:
@@ -52,8 +74,12 @@ def main(argv=None) -> int:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--skip-verify", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--impl", choices=["reference", "pallas"], default="reference",
+                    help="attention/SSD kernels: jnp reference, or the Pallas "
+                         "kernels compiled by Mosaic (TPU only)")
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
 
     # ---- 1. verification gate (paper technique) ---------------------------------
@@ -91,48 +117,49 @@ def main(argv=None) -> int:
         return 1
     mesh = make_debug_mesh(tp=args.tp, dp=args.dp)
     ctx = ParallelCtx.from_mesh(mesh, dp=("data",), sp=args.sp)
-    model = Model(cfg, ctx)
+    model = Model(cfg, ctx, impl=args.impl)
     tcfg = TrainConfig(opt=AdamWConfig(lr=args.lr, warmup_steps=10,
                                        total_steps=max(args.steps, 100)),
                        microbatches=args.micro, remat=False, zero1=args.zero1,
                        grad_compress=args.compress)
 
-    key = jax.random.PRNGKey(args.seed)
-    params = Model(cfg).init(key)
-    opt = adamw_init(params)
+    params, opt, shardings = init_state(model, mesh, jax.random.PRNGKey(args.seed))
     start_step = 0
     ckpt_dir = Path(args.ckpt_dir) if args.ckpt_dir else None
     if args.resume and ckpt_dir:
         latest = ckpt.latest(ckpt_dir)
         if latest:
-            (params, opt), meta = (
-                ckpt.restore(latest, jax.eval_shape(lambda: (params, opt)))
-            )
+            (params, opt), meta = ckpt.restore(
+                latest, jax.eval_shape(lambda: (params, opt)), shardings=shardings)
             start_step = meta["step"]
             print(f"[ckpt] resumed from {latest} at step {start_step}")
 
-    pspecs = param_specs(jax.eval_shape(lambda: params))
-    ospecs = {"m": pspecs, "v": pspecs, "step": P()}
+    pspecs, ospecs = jax.tree_util.tree_map(lambda s: s.spec, shardings)
     data = SyntheticLM(DataConfig(cfg.vocab, args.seq, args.batch, seed=args.seed))
     sample = data.batch_at(0)
     bspecs = batch_spec(sample, ("data",))
     mspecs = {"loss": P(), "grad_norm": P(), "lr": P()}
-    from repro.compat import shard_map
+    # params and moments are updated in place: their old buffers are donated
     step_fn = jax.jit(shard_map(
         make_step_fn(model, tcfg), mesh=mesh,
         in_specs=(pspecs, ospecs, bspecs), out_specs=(pspecs, ospecs, mspecs),
-        check_vma=False))
+        check_vma=False), donate_argnums=(0, 1))
 
     t0 = time.time()
+    nonfinite = False  # any step's loss non-finite; read only when logging
     with mesh:
         for step in range(start_step, args.steps):
             batch = data.batch_at(step)
             params, opt, metrics = step_fn(params, opt, batch)
+            nonfinite = nonfinite | ~jnp.isfinite(metrics["loss"])
             if step % 10 == 0 or step == args.steps - 1:
                 print(f"step {step:5d} loss {float(metrics['loss']):.4f} "
                       f"gnorm {float(metrics['grad_norm']):.3f} "
                       f"lr {float(metrics['lr']):.2e} "
                       f"({(time.time()-t0)/(step-start_step+1):.2f}s/step)")
+                if nonfinite:
+                    print(f"[abort] non-finite loss by step {step}")
+                    return 3
             if ckpt_dir and (step + 1) % args.ckpt_every == 0:
                 ckpt.save(ckpt_dir, step + 1, (params, opt))
                 print(f"[ckpt] saved step {step + 1}")

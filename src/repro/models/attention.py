@@ -13,6 +13,7 @@ flash-decoding pattern the paper verifies (§7.1).
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Optional
 
 import jax
@@ -118,6 +119,29 @@ def chunked_attention(
     return out
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(3,))
+def flash_pallas(q, k, v, causal: bool):
+    """Attention through the Pallas flash kernel (Mosaic; TPU only).  The
+    backward pass is the vjp of ``chunked_attention``, recomputed from the
+    saved inputs."""
+    # imported here: loading Pallas adds seconds to every cold verify
+    from repro.kernels import ops as kops
+
+    return kops.flash_attention(q, k, v, causal=causal)
+
+
+def _flash_pallas_fwd(q, k, v, causal):
+    return flash_pallas(q, k, v, causal), (q, k, v)
+
+
+def _flash_pallas_bwd(causal, res, g):
+    _, vjp = jax.vjp(lambda *a: chunked_attention(*a, causal=causal), *res)
+    return vjp(g)
+
+
+flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
+
+
 def attn_fwd(cfg, ctx: ParallelCtx, p, x, positions, *, impl: str = "reference",
              unroll: bool = False):
     """Full-sequence attention (train / prefill).  x: (B, S, D) replicated
@@ -138,9 +162,7 @@ def attn_fwd(cfg, ctx: ParallelCtx, p, x, positions, *, impl: str = "reference",
     q = apply_rope(q, positions, cfg.rope_fraction, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
     if impl == "pallas":
-        from repro.kernels import ops as kops
-
-        out = kops.flash_attention(q, k, v, causal=cfg.causal)
+        out = flash_pallas(q, k, v, cfg.causal)
     else:
         out = chunked_attention(q, k, v, causal=cfg.causal, unroll=unroll)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, Hq_loc * hd)

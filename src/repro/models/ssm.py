@@ -14,6 +14,7 @@ output row-projection needs a psum.  Decode keeps O(1) state per head.
 """
 from __future__ import annotations
 
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -127,6 +128,28 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None, return_state: boo
     return y
 
 
+@partial(jax.custom_vjp, nondiff_argnums=(5,))
+def ssd_pallas(x, dt, A, Bm, Cm, chunk: int):
+    """SSD scan through the Pallas kernel (Mosaic; TPU only).  The backward
+    pass is the vjp of ``ssd_chunked``, recomputed from the saved inputs."""
+    # imported here: loading Pallas adds seconds to every cold verify
+    from repro.kernels import ops as kops
+
+    return kops.ssd_scan(x, dt, A, Bm, Cm, chunk=chunk)
+
+
+def _ssd_pallas_fwd(x, dt, A, Bm, Cm, chunk):
+    return ssd_pallas(x, dt, A, Bm, Cm, chunk), (x, dt, A, Bm, Cm)
+
+
+def _ssd_pallas_bwd(chunk, res, g):
+    _, vjp = jax.vjp(lambda *a: ssd_chunked(*a, chunk), *res)
+    return vjp(g)
+
+
+ssd_pallas.defvjp(_ssd_pallas_fwd, _ssd_pallas_bwd)
+
+
 def ssm_fwd(cfg, ctx: ParallelCtx, p, x, *, impl: str = "reference",
             unroll: bool = False):
     """Full-sequence SSD block.  x: (B, S, D) replicated."""
@@ -151,9 +174,7 @@ def ssm_fwd(cfg, ctx: ParallelCtx, p, x, *, impl: str = "reference",
     A = -jnp.exp(p["A_log"][..., :H_loc].astype(jnp.float32))
     xh = xproj.reshape(B, S, H_loc, P)
     if impl == "pallas":
-        from repro.kernels import ops as kops
-
-        y = kops.ssd_scan(xh, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
+        y = ssd_pallas(xh, dt, A, Bm, Cm, cfg.ssm_chunk)
     else:
         y = ssd_chunked(xh, dt, A, Bm, Cm, cfg.ssm_chunk, unroll=unroll)
     y = y + (p["Dskip"][..., :H_loc])[..., None] * xh.astype(jnp.float32)

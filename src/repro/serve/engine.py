@@ -118,6 +118,7 @@ class Engine:
             self.params, jnp.asarray(tokens), self.caches, jnp.int32(pos))
         if not sample:
             return None
+        logits = logits[:, : self.model.cfg.vocab]  # drop TP vocab padding
         if self.cfg.temperature <= 0:
             return np.asarray(jnp.argmax(logits, axis=-1))
         self._key, sub = jax.random.split(self._key)

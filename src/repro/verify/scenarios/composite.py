@@ -22,9 +22,9 @@ from __future__ import annotations
 import time
 
 import jax
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.core.trace import trace_sharded
 from repro.core.verifier import OutputSpec
 from repro.parallel.ctx import ParallelCtx
@@ -68,7 +68,7 @@ def tpdp_forward(arch: str, cfg, plan, scen, ctx: BuildCtx) -> GraphPair:
     # baseline: the 1D TP per-device program over the full batch — the same
     # trace as tp-forward's distributed side, shared through the session's
     # base-trace cache when the shape knobs coincide (e.g. explicit batch=)
-    mesh_tp = abstract_mesh((tp,), (TP_AXIS,))
+    mesh_tp = AbstractMesh((tp,), (TP_AXIS,))
     bspecs_tp = jax.tree_util.tree_map(lambda _: P(), b)
     gb, b_in = ctx.trace_base_sharded(
         f"fwd:dense:dist:tp{tp}",
@@ -76,7 +76,7 @@ def tpdp_forward(arch: str, cfg, plan, scen, ctx: BuildCtx) -> GraphPair:
         param_shapes, b, name=f"{arch}-tp-base")
 
     # distributed: the 2D (data, model) per-device program, batch sharded
-    mesh_2d = abstract_mesh((dp, tp), (DP_AXIS, TP_AXIS))
+    mesh_2d = AbstractMesh((dp, tp), (DP_AXIS, TP_AXIS))
     bspecs_2d = jax.tree_util.tree_map(lambda _: P(DP_AXIS), b)
     gd, d_in, _ = trace_sharded(
         fn, mesh_2d, (pspecs, bspecs_2d), P(DP_AXIS, None, TP_AXIS),

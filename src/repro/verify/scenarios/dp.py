@@ -12,9 +12,9 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.core.trace import trace_sharded
 from repro.core.verifier import OutputSpec
 from repro.parallel.ctx import ParallelCtx
@@ -38,7 +38,7 @@ def _dp_setup(arch: str, cfg, dp: int, batch: int, seq: int):
             f"ids — DP plans for MoE archs are covered by numerical tests")
     if batch % dp:
         raise PlanError(f"batch={batch} not divisible by dp={dp}")
-    mesh = abstract_mesh((dp,), (DP_AXIS,))
+    mesh = AbstractMesh((dp,), (DP_AXIS,))
     pctx = ParallelCtx(dp_axis=(DP_AXIS,), dp_size=dp)
     model_s, model_d, param_shapes = model_pair(cfg, pctx)
     pspecs = jax.tree_util.tree_map(lambda _: P(), param_shapes)

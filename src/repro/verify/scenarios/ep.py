@@ -17,9 +17,9 @@ expert axis in isolation (per-technique verification).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.core.trace import trace_sharded
 from repro.core.verifier import OutputSpec
 from repro.parallel.ctx import ParallelCtx
@@ -40,7 +40,7 @@ from .registry import DEFAULT_SCENARIOS as S
 
 def _ep_forward_parts(arch: str, cfg, ep: int, batch: int, seq: int,
                       ctx: BuildCtx):
-    mesh = abstract_mesh((ep,), (TP_AXIS,))
+    mesh = AbstractMesh((ep,), (TP_AXIS,))
     pctx = ParallelCtx(ep_axis=TP_AXIS, ep_size=ep)
     model_s, model_d, param_shapes = model_pair(cfg, pctx, moe_impl="ep")
     pspecs = ep_pspecs(param_shapes, cfg, TP_AXIS)

@@ -8,9 +8,9 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.core.trace import trace_sharded
 from repro.core.verifier import OutputSpec
 from repro.models.model import _tree_index
@@ -45,7 +45,7 @@ def stage_pair(arch: str, cfg, tp: int, stg: int, stages: int,
     first, last = stg == 0, stg == stages - 1
 
     t0 = time.perf_counter()
-    mesh = abstract_mesh((tp,), (TP_AXIS,))
+    mesh = AbstractMesh((tp,), (TP_AXIS,))
     pctx = ParallelCtx(tp_axis=TP_AXIS, tp_size=tp, ep_axis=TP_AXIS, ep_size=tp)
     model_s, model_d, param_shapes = model_pair(cfg, pctx)
     pspecs = verify_pspecs(param_shapes, cfg)

@@ -12,9 +12,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.core.trace import trace_sharded
 from repro.core.verifier import OutputSpec
 from repro.parallel.ctx import ParallelCtx
@@ -36,7 +36,7 @@ from .registry import DEFAULT_SCENARIOS as S
 def _tp_forward_parts(arch: str, cfg, tp: int, batch: int, seq: int,
                       ctx: BuildCtx, sp: bool = False):
     """Trace the (baseline, per-device) TP forward pair for ``cfg``."""
-    mesh = abstract_mesh((tp,), (TP_AXIS,))
+    mesh = AbstractMesh((tp,), (TP_AXIS,))
     pctx = ParallelCtx(tp_axis=TP_AXIS, tp_size=tp, ep_axis=TP_AXIS,
                        ep_size=tp, sp=sp)
     model_s, model_d, param_shapes = model_pair(cfg, pctx)
@@ -84,7 +84,7 @@ def _tp_decode_parts(arch: str, cfg, tp: int, batch: int, max_len: int,
     """Trace the (baseline, per-device) decode-step pair for ``cfg``."""
     from repro.parallel.sharding import cache_specs as _cache_specs
 
-    mesh = abstract_mesh((tp,), (TP_AXIS,))
+    mesh = AbstractMesh((tp,), (TP_AXIS,))
     pctx = ParallelCtx(tp_axis=TP_AXIS, tp_size=tp, ep_axis=TP_AXIS, ep_size=tp)
     model_s, model_d, param_shapes = model_pair(cfg, pctx)
     pspecs = verify_pspecs(param_shapes, cfg)

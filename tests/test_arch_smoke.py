@@ -73,3 +73,22 @@ def test_arch_grads_finite(arch):
     assert np.isfinite(float(loss))
     leaves = jax.tree_util.tree_leaves(grads)
     assert all(np.isfinite(np.asarray(g, np.float32)).all() for g in leaves), f"{arch}: NaN grads"
+
+
+def test_engine_never_emits_padded_vocab_ids():
+    """Logits carry TP vocab padding (vocab_p > vocab); the serving engine
+    samples only real ids even when a padded column scores highest."""
+    import dataclasses
+
+    from repro.serve import Engine, ServeConfig
+
+    base = get_config("mamba2_130m", smoke=True)
+    cfg = dataclasses.replace(base, vocab_padded=base.vocab + 8)
+    m = Model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    w = params["lm_head"]["w"]
+    params["lm_head"]["w"] = w.at[:, cfg.vocab:].set(100.0)
+    eng = Engine(m, params, ServeConfig(max_len=16, batch_slots=1))
+    eng.submit([1, 2, 3], max_new=4)
+    (ids,) = eng.run().values()
+    assert len(ids) == 4 and all(0 <= t < cfg.vocab for t in ids), ids

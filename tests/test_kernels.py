@@ -105,3 +105,49 @@ def test_ssd_decode_state_consistency():
         outs.append(np.asarray(y, np.float32))
     dec = np.concatenate(outs, axis=1)
     np.testing.assert_allclose(dec, full, rtol=2e-3, atol=2e-3)
+
+
+@pytest.fixture
+def interpret_kernels(monkeypatch):
+    import functools
+
+    monkeypatch.setattr(ops, "flash_attention",
+                        functools.partial(ops.flash_attention, interpret=True))
+    monkeypatch.setattr(ops, "ssd_scan", functools.partial(ops.ssd_scan, interpret=True))
+
+
+def _assert_vjp_matches(fn, ref_fn, args, tol):
+    """Same forward, and the same gradients of a random projection."""
+    out, vjp = jax.vjp(fn, *args)
+    ref, ref_vjp = jax.vjp(ref_fn, *args)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+    ct = jax.random.normal(jax.random.PRNGKey(11), out.shape, out.dtype)
+    for g, r in zip(vjp(ct), ref_vjp(ct)):
+        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(r, np.float32),
+                                   rtol=tol, atol=tol)
+
+
+def test_ssd_pallas_vjp_matches_chunked(interpret_kernels):
+    from repro.models.ssm import ssd_pallas
+
+    ks = jax.random.split(KEY, 5)
+    B, S, H, P, N, chunk = 1, 128, 2, 32, 16, 64
+    args = (jax.random.normal(ks[0], (B, S, H, P)),
+            jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))),
+            -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5),
+            jax.random.normal(ks[3], (B, S, N)), jax.random.normal(ks[4], (B, S, N)))
+    _assert_vjp_matches(lambda *a: ssd_pallas(*a, chunk),
+                        lambda *a: ssd_chunked(*a, chunk), args, 5e-4)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_pallas_vjp_matches_chunked(interpret_kernels, causal):
+    from repro.models.attention import flash_pallas
+
+    ks = jax.random.split(KEY, 3)
+    args = (jax.random.normal(ks[0], (1, 4, 128, 64)),
+            jax.random.normal(ks[1], (1, 2, 128, 64)),
+            jax.random.normal(ks[2], (1, 2, 128, 64)))
+    _assert_vjp_matches(lambda *a: flash_pallas(*a, causal),
+                        lambda *a: chunked_attention(*a, causal=causal), args, 2e-4)

@@ -37,7 +37,7 @@ def test_pipeline_matches_sequential():
         for s in range(N_STAGES):
             ref = jax.vmap(lambda xx: stage_fn(Ws[s], xx))(ref)
 
-        from repro.compat import shard_map
+        from jax import shard_map
         fn = shard_map(
             lambda w, xx: pipeline_forward(lambda p, h: stage_fn(p[0], h), w, xx,
                                            n_stages=N_STAGES),
@@ -79,7 +79,7 @@ def test_pipeline_grad_matches_sequential():
             return jnp.sum(out * out) / N_STAGES
 
         gref = jax.grad(seq_loss)(Ws)
-        from repro.compat import shard_map
+        from jax import shard_map
         fn = shard_map(jax.grad(pipe_loss), mesh=mesh,
                            in_specs=(P("pod"), P()), out_specs=P("pod"),
                            check_vma=False)

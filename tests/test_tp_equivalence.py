@@ -67,7 +67,7 @@ FWD_TEMPLATE = _PRELUDE + textwrap.dedent("""
     arch = "{arch}"
     cfg, mesh, ctx, md, ms, params, batch, pspecs, bspecs = tp_forward(arch, sp={sp})
     ref = np.asarray(ms.loss(params, batch), np.float32)
-    from repro.compat import shard_map
+    from jax import shard_map
     fn = shard_map(lambda p, b: jax.lax.pmean(md.loss(p, b), "data"), mesh=mesh,
                    in_specs=(pspecs, bspecs), out_specs=P(), check_vma=False)
     with mesh:
@@ -121,7 +121,7 @@ TRAIN_TEMPLATE = _PRELUDE + textwrap.dedent("""
         opt = adamw_init(params)
         ospecs = {{"m": pspecs, "v": pspecs, "step": P()}}
     mspecs = {{"loss": P(), "grad_norm": P(), "lr": P()}}
-    from repro.compat import shard_map
+    from jax import shard_map
     fn = shard_map(step, mesh=mesh, in_specs=(pspecs, ospecs, bspecs),
                        out_specs=(pspecs, ospecs, mspecs), check_vma=False)
     with mesh:
@@ -184,7 +184,7 @@ CP_TEMPLATE = _PRELUDE + textwrap.dedent("""
     cshapes = jax.eval_shape(lambda: ms.init_cache(B, MAXLEN))
     cspecs = cache_specs(cshapes, None, cp="data")
     cache_d = ms.init_cache(B, MAXLEN)  # zeros; same content
-    from repro.compat import shard_map
+    from jax import shard_map
     fn = shard_map(lambda p, t, c, q: md.decode_step(p, t, c, q)[0],
                        mesh=mesh, in_specs=(pspecs, P(), cspecs, P()),
                        out_specs=P(None, "model"), check_vma=False)

@@ -67,3 +67,29 @@ def test_elastic_resume_different_mesh(tmp_path):
                   "--skip-verify"], devices=4)
     assert "resumed" in out
     assert _losses(out), out
+
+
+def test_launcher_aborts_on_nonfinite_loss(monkeypatch, tmp_path, capsys):
+    """A step whose loss is NaN makes the launcher exit nonzero at the next
+    logged step instead of reporting success."""
+    import jax.numpy as jnp
+
+    from repro.launch import train
+
+    make = train.make_step_fn
+
+    def nan_loss_step(model, tcfg):
+        step = make(model, tcfg)
+
+        def wrapped(params, opt, batch):
+            params, opt, metrics = step(params, opt, batch)
+            return params, opt, {**metrics, "loss": metrics["loss"] * jnp.nan}
+
+        return wrapped
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(train, "make_step_fn", nan_loss_step)
+    rc = train.main(["--arch", "mamba2_130m", "--smoke", "--steps", "2",
+                     "--seq", "32", "--batch", "2"])
+    assert rc == 3
+    assert "[abort] non-finite loss by step 0" in capsys.readouterr().out

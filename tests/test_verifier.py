@@ -3,9 +3,8 @@ the injected-bug suite (paper Tables 4/5 analogue), and framework layers."""
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import abstract_mesh
 
 from repro.core import (
     inject_all,
@@ -61,7 +60,7 @@ def test_verify_without_partitioning_agrees():
 
 @pytest.fixture(scope="module")
 def traced_pair():
-    mesh = abstract_mesh((C,), ("model",))
+    mesh = AbstractMesh((C,), ("model",))
     gb, b_in, _ = trace(base_fn, *AVALS, name="base")
     gd, d_in, _ = trace_sharded(dist_fn, mesh, SPECS, P(), *AVALS)
     facts = [InputFact(DUP, 0, 0), InputFact(SHARD, 1, 1, 2), InputFact(SHARD, 2, 2, 1)]
