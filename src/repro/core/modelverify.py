@@ -136,11 +136,7 @@ def __getattr__(name: str):
         "_spec_input_facts": _specs.spec_input_facts,
     }
     if name == "_stamped_pair":
-        def _stamped_pair(cfg, pair_fn, periods_per_block):
-            parts, _ = _pairs._stamped_parts(cfg, pair_fn, periods_per_block)
-            return parts
-
-        return _stamped_pair
+        return _pairs._stamped_parts
     if name in legacy:
         return legacy[name]
     raise AttributeError(name)

@@ -26,6 +26,7 @@ from typing import Optional, Sequence
 
 from .ir import Graph
 from .rules import Propagator
+from .spans import span
 
 
 @dataclass
@@ -334,7 +335,8 @@ class PartitionedVerifier:
 
     # -- main loop --------------------------------------------------------------
     def run(self) -> MemoStats:
-        plans = partition_layers(self.prop.base, self.prop.dist)
+        with span("rules.partition"):
+            plans = partition_layers(self.prop.base, self.prop.dist)
         pool = None
         if self.workers > 1 and self.engine is None:
             # one pool for the whole run (pass-engine Fig. 5 path)
@@ -346,36 +348,43 @@ class PartitionedVerifier:
                 self.stats.layers += 1
                 fp = ext = None
                 if self.memoize and isinstance(plan.key, int):
-                    ext = self._plan_ext(plan)
-                    fp = self._fingerprint(plan, ext)
+                    with span("rules.fingerprint"):
+                        ext = self._plan_ext(plan)
+                        fp = self._fingerprint(plan, ext)
                 if fp is not None and fp in self._memo:
                     self.stats.memo_hits += 1
-                    self._replay(self._memo[fp], plan, ext[0])
+                    with span("rules.replay"):
+                        self._replay(self._memo[fp], plan, ext[0])
                     continue
-                self._rewrite_layer(plan, pool)
-                if fp is not None:
-                    inside_b = set(plan.base_nodes)
-                    ext_b_set = set(ext[0])
-                    # fusion-discharged facts (and their closure cascade) are
-                    # excluded from the template: they can rest on e-class
-                    # merges crossing layer boundaries (content-addressed
-                    # leaves are global), so replaying them positionally into
-                    # another layer is not covered by the layer-local
-                    # fingerprint — the post-replay settle re-derives them
-                    fkeys = self.prop.fusion_keys
-                    facts = [
-                        f
-                        for d in plan.dist_nodes
-                        for f in self.prop.store.facts(d)
-                        if (f.base in inside_b or f.base in ext_b_set)
-                        and f.key() not in fkeys
-                    ]
-                    self._memo[fp] = (sorted(plan.base_nodes),
-                                      sorted(plan.dist_nodes), ext[0], facts)
+                with span("rules.rewrite"):
+                    self._rewrite_layer(plan, pool)
+                    if fp is not None:
+                        self._memo[fp] = self._template(plan, ext[0])
         finally:
             if pool is not None:
                 pool.shutdown(wait=True)
         return self.stats
+
+    def _template(self, plan: LayerPlan, base_ext: list[int]) -> tuple:
+        """The memo entry of a layer just rewritten: its facts, positional
+        over its own and its external baseline nodes."""
+        inside_b = set(plan.base_nodes)
+        ext_b_set = set(base_ext)
+        # fusion-discharged facts (and their closure cascade) are excluded
+        # from the template: they can rest on e-class merges crossing layer
+        # boundaries (content-addressed leaves are global), so replaying them
+        # positionally into another layer is not covered by the layer-local
+        # fingerprint — the post-replay settle re-derives them
+        fkeys = self.prop.fusion_keys
+        facts = [
+            f
+            for d in plan.dist_nodes
+            for f in self.prop.store.facts(d)
+            if (f.base in inside_b or f.base in ext_b_set)
+            and f.key() not in fkeys
+        ]
+        return (sorted(plan.base_nodes), sorted(plan.dist_nodes), base_ext,
+                facts)
 
     def _rewrite_layer(self, plan: LayerPlan, pool=None) -> None:
         if self.engine is not None:

@@ -67,9 +67,15 @@ def rank_bug_sites(sites: list) -> list:
     return sorted(sites, key=lambda b: b.rank)
 
 
+# the verdict's top-level spans (repro.core.spans), each read into the
+# PhaseTimings field ``<phase>_s``
+PHASES = ("trace", "stamp", "rules", "localize")
+
+
 @dataclass
 class PhaseTimings:
-    """Wall-clock breakdown of one verification call (seconds)."""
+    """Wall-clock breakdown of one verification call (seconds), read from
+    the verdict's span tree (:mod:`repro.core.spans`)."""
 
     trace_s: float = 0.0  # jax tracing -> TensorIR (0 on a graph-cache hit)
     stamp_s: float = 0.0  # periodicity validation + IR cloning
@@ -79,10 +85,35 @@ class PhaseTimings:
     # populated under VerifyOptions(profile=True) — off by default because
     # the per-invocation clock reads cost ~15% on the rules phase
     profile: Optional[dict] = None
+    # every span aggregated by name: {name: {"calls", "s", "self_s"}}, "s"
+    # inclusive, "self_s" less the time covered by child spans
+    spans: dict = field(default_factory=dict)
+    # of which: garbage-collector pauses, and collections by generation
+    gc_s: float = 0.0
+    gc_collections: list = field(default_factory=lambda: [0, 0, 0])
 
     @property
     def total_s(self) -> float:
         return self.trace_s + self.stamp_s + self.rules_s + self.localize_s
+
+    @classmethod
+    def merge(cls, items: list) -> "PhaseTimings":
+        """The sum of several scenarios' timings."""
+        spans: dict = {}
+        for t in items:
+            for name, a in t.spans.items():
+                m = spans.setdefault(name, {"calls": 0, "s": 0.0, "self_s": 0.0})
+                for k in m:
+                    m[k] += a[k]
+        return cls(
+            **{f"{p}_s": sum(getattr(t, f"{p}_s") for t in items)
+               for p in PHASES},
+            profile=RuleProfiler.merge_summaries([t.profile for t in items]),
+            spans=spans,
+            gc_s=sum(t.gc_s for t in items),
+            gc_collections=[sum(g) for g in zip(*(t.gc_collections
+                                                  for t in items))],
+        )
 
 
 def op_family(op: str) -> str:

@@ -21,6 +21,7 @@ import jax
 import numpy as np
 
 from .ir import Graph
+from .spans import span
 
 # jaxpr primitive -> IR op (1:1 renames; anything absent falls through opaque)
 _PRIM_MAP = {
@@ -478,7 +479,8 @@ def trace(
     to the product of enclosing trip counts — for FLOP/collective accounting
     only (stacked-output shapes are not reconstructed), not for verification.
     """
-    closed = jax.make_jaxpr(fn)(*avals)
+    with span("trace.jaxpr"):
+        closed = jax.make_jaxpr(fn)(*avals)
     t = Tracer(layer_tag_fn, scan_inline=scan_inline)
     t.g.name = name
     flat_avals = jax.tree_util.tree_leaves(avals)
@@ -486,7 +488,8 @@ def trace(
         t.g.add("input", (), tuple(a.shape), str(a.dtype), {"arg": i})
         for i, a in enumerate(flat_avals)
     ]
-    out_ids = t.trace_jaxpr(closed.jaxpr, closed.consts, in_ids)
+    with span("trace.ir"):
+        out_ids = t.trace_jaxpr(closed.jaxpr, closed.consts, in_ids)
     t.g.mark_output(*out_ids)
     # outer global-shape leaf -> per-shard re-issued leaf (layer stamping
     # grows the dead outer leaves alongside their per-shard aliases)

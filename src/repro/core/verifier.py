@@ -7,12 +7,11 @@ and its shard_map distribution and verifies them in one call.
 The *model-level* public API lives in :mod:`repro.verify` (``Session`` /
 ``Plan`` / ``Report``): it owns the cross-call state (persistent worker
 pool, trace + template caches) and calls ``verify_graphs`` with the
-``cache``/``pool``/``timings`` hooks below.  ``repro.launch.train`` /
+``cache``/``pool`` hooks below.  ``repro.launch.train`` /
 ``serve`` run their pre-flight gates through it.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -22,8 +21,9 @@ from jax.sharding import AbstractMesh, PartitionSpec
 from .ir import ELEMENTWISE, Graph, LEAF_OPS
 from .partition import PartitionedVerifier, TemplateCache
 from .relations import DUP, PARTIAL, SHARD, Diagnostic, RelStore
-from .report import BugSite, CacheStats, PhaseTimings, Report, rank_bug_sites
+from .report import BugSite, CacheStats, Report, rank_bug_sites
 from .rules import Propagator, WorklistEngine
+from .spans import span, verdict
 from .trace import trace, trace_sharded
 
 
@@ -286,6 +286,62 @@ def _output_sites(
     return rank_bug_sites(sites)
 
 
+def _rules(base: Graph, dist: Graph, size: int, input_facts, base_inputs,
+           dist_inputs, options: VerifyOptions, cache, pool):
+    """The rules phase: register the input facts and derive every fact to
+    fixpoint, layer by layer.  Returns ``(propagator, memo stats)``."""
+    backend = resolve_backend(options)
+    with span("rules.egraph_build"):
+        prop = Propagator(base, dist, size, axis=options.axis,
+                          fusion=options.fusion)
+        if options.profile:
+            from .report import RuleProfiler
+
+            prop.profiler = RuleProfiler()
+        engine = (WorklistEngine(prop, workers=options.parallel_workers,
+                                 pool=pool, backend=backend,
+                                 cone_cap=options.chunk_cone_cap,
+                                 min_offload=options.chunk_min_offload,
+                                 per_worker=options.chunks_per_worker)
+                  if options.engine == "worklist" else None)
+        for f in input_facts:
+            b, d = base_inputs[f.base_index], dist_inputs[f.dist_index]
+            if f.kind == DUP:
+                prop.register_dup(b, d)
+            elif f.kind == SHARD:
+                prop.register_shard(b, d, f.dim)
+            else:
+                raise ValueError(f.kind)
+    if (engine is not None and backend == "process"
+            and options.parallel_workers > 1):
+        engine.start_offload()
+    memo = None
+    try:
+        if options.partition:
+            pv = PartitionedVerifier(prop, options.parallel_workers, options.memoize,
+                                     engine=engine, cache=cache)
+            memo = pv.run()
+            with span("rules.cleanup"):
+                if engine is not None:
+                    # cross-layer cleanup: never-visited nodes plus the
+                    # pending consumers of facts that crossed layer
+                    # boundaries (settled memo-hit layers are not
+                    # re-dispatched)
+                    engine.run()
+                else:
+                    prop.run(max_passes=2)  # cross-layer cleanup passes
+        else:
+            with span("rules.rewrite"):
+                if engine is not None:
+                    engine.run()
+                else:
+                    prop.run(max_passes=options.max_passes)
+    finally:
+        if engine is not None:
+            engine.close()
+    return prop, memo
+
+
 def verify_graphs(
     base: Graph,
     dist: Graph,
@@ -298,82 +354,40 @@ def verify_graphs(
     options: Optional[VerifyOptions] = None,
     cache: Optional[TemplateCache] = None,
     pool=None,
-    timings: Optional[PhaseTimings] = None,
 ) -> Report:
     """Verify a traced graph pair.
 
-    ``cache``/``pool``/``timings`` are the :class:`repro.verify.Session`
-    hooks: a :class:`TemplateCache` valid for this exact graph pair, a
-    session-owned thread pool for the worklist engine's parallel sweep, and
-    a pre-filled :class:`PhaseTimings` (trace/stamp) this call completes
-    with the rules/localize phases."""
-    t0 = time.perf_counter()
+    ``cache``/``pool`` are the :class:`repro.verify.Session` hooks: a
+    :class:`TemplateCache` valid for this exact graph pair, and a
+    session-owned thread pool for the worklist engine's parallel sweep.
+    The call is a verdict of its own, or part of the one a
+    ``Session.verify`` has open (:mod:`repro.core.spans`)."""
     options = options or VerifyOptions()
-    timings = timings if timings is not None else PhaseTimings()
     if options.engine not in ("worklist", "passes"):
         raise ValueError(f"unknown engine {options.engine!r}: worklist|passes")
-    backend = resolve_backend(options)
-    prop = Propagator(base, dist, size, axis=options.axis,
-                      fusion=options.fusion)
-    if options.profile:
-        from .report import RuleProfiler
-
-        prop.profiler = RuleProfiler()
-    engine = (WorklistEngine(prop, workers=options.parallel_workers,
-                             pool=pool, backend=backend,
-                             cone_cap=options.chunk_cone_cap,
-                             min_offload=options.chunk_min_offload,
-                             per_worker=options.chunks_per_worker)
-              if options.engine == "worklist" else None)
-    for f in input_facts:
-        b, d = base_inputs[f.base_index], dist_inputs[f.dist_index]
-        if f.kind == DUP:
-            prop.register_dup(b, d)
-        elif f.kind == SHARD:
-            prop.register_shard(b, d, f.dim)
-        else:
-            raise ValueError(f.kind)
-    if (engine is not None and backend == "process"
-            and options.parallel_workers > 1):
-        engine.start_offload()
-    memo = None
-    try:
-        if options.partition:
-            pv = PartitionedVerifier(prop, options.parallel_workers, options.memoize,
-                                     engine=engine, cache=cache)
-            memo = pv.run()
-            if engine is not None:
-                # cross-layer cleanup: never-visited nodes plus the pending
-                # consumers of facts that crossed layer boundaries (settled
-                # memo-hit layers are not re-dispatched)
-                engine.run()
-            else:
-                prop.run(max_passes=2)  # cross-layer cleanup passes
-        elif engine is not None:
-            engine.run()
-        else:
-            prop.run(max_passes=options.max_passes)
-    finally:
-        if engine is not None:
-            engine.close()
-    t_rules = time.perf_counter()
-    timings.rules_s = t_rules - t0
+    with verdict() as rec:
+        mark = rec.mark()
+        with span("rules"):
+            prop, memo = _rules(base, dist, size, input_facts, base_inputs,
+                                dist_inputs, options, cache, pool)
+        with span("localize"):
+            specs = list(output_specs or [OutputSpec()] * len(dist.outputs))
+            outputs_ok = [
+                _output_ok(prop.store, b, d, s, size)
+                for b, d, s in zip(base.outputs, dist.outputs, specs)
+            ]
+            verified = all(outputs_ok)
+            sites = [] if verified else localize(base, dist, prop.store)
+            if not verified and not sites:
+                sites = _output_sites(base, dist, prop.store, specs, outputs_ok)
+            unverified = sum(
+                1 for n in dist
+                if n.op not in LEAF_OPS and not prop.store.verified(n.id)
+            )
+        timings = rec.timings(mark)
+        elapsed_s = rec.elapsed(mark)
     if prop.profiler is not None:
         timings.profile = prop.profiler.summary()
-
-    specs = list(output_specs or [OutputSpec()] * len(dist.outputs))
-    outputs_ok = [
-        _output_ok(prop.store, b, d, s, size)
-        for b, d, s in zip(base.outputs, dist.outputs, specs)
-    ]
-    verified = all(outputs_ok)
-    sites = [] if verified else localize(base, dist, prop.store)
-    if not verified and not sites:
-        sites = _output_sites(base, dist, prop.store, specs, outputs_ok)
-    unverified = sum(
-        1 for n in dist if n.op not in LEAF_OPS and not prop.store.verified(n.id)
-    )
-    timings.localize_s = time.perf_counter() - t_rules
     return Report(
         verified=verified,
         outputs_ok=outputs_ok,
@@ -382,7 +396,7 @@ def verify_graphs(
         num_facts=prop.store.num_derived,
         num_base_nodes=len(base.nodes),
         num_dist_nodes=len(dist.nodes),
-        elapsed_s=time.perf_counter() - t0,
+        elapsed_s=elapsed_s,
         memo=memo,
         unverified_count=unverified,
         rule_invocations=prop.rule_invocations,

@@ -143,8 +143,9 @@ def _ssd_pallas_fwd(x, dt, A, Bm, Cm, chunk):
 
 
 def _ssd_pallas_bwd(chunk, res, g):
-    _, vjp = jax.vjp(lambda *a: ssd_chunked(*a, chunk), *res)
-    return vjp(g)
+    with jax.named_scope("ssd_bwd"):  # names the jnp backward's device ops
+        _, vjp = jax.vjp(lambda *a: ssd_chunked(*a, chunk), *res)
+        return vjp(g)
 
 
 ssd_pallas.defvjp(_ssd_pallas_fwd, _ssd_pallas_bwd)

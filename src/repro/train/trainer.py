@@ -43,13 +43,15 @@ def local_grads(model: Model, tcfg: TrainConfig, params, batch):
     """Per-device loss + grads with microbatch accumulation (no DP reduction)."""
     loss_of = lambda p, b: model.loss(p, b, remat=tcfg.remat, unroll=tcfg.unroll_layers)
     if tcfg.microbatches <= 1:
-        loss, grads = jax.value_and_grad(loss_of)(params, batch)
+        with jax.named_scope("microbatch"):
+            loss, grads = jax.value_and_grad(loss_of)(params, batch)
         return loss, grads
     micro = _split_micro(batch, tcfg.microbatches)
 
     def body(carry, mb):
         acc_loss, acc_g = carry
-        loss, g = jax.value_and_grad(loss_of)(params, mb)
+        with jax.named_scope("microbatch"):
+            loss, g = jax.value_and_grad(loss_of)(params, mb)
         acc_g = jax.tree_util.tree_map(jnp.add, acc_g, g)
         return (acc_loss + loss, acc_g), None
 
@@ -77,13 +79,17 @@ def make_step_fn(model: Model, tcfg: TrainConfig, shard_flags=None):
             scatter_axis, others = axes[-1], axes[:-1]
             if others:
                 grads = jax.tree_util.tree_map(lambda g: lax.psum(g, others), grads)
-            new_p, new_s, info = zero1_update(
-                tcfg.opt, params, grads, opt_state, scatter_axis, sizes[-1], shard_flags)
+            with jax.named_scope("optimizer"):
+                new_p, new_s, info = zero1_update(
+                    tcfg.opt, params, grads, opt_state, scatter_axis, sizes[-1],
+                    shard_flags)
         else:
             if ctx.dp_axis:
                 grads = allreduce_compressed(grads, tcfg.grad_compress, ctx.dp_axis)
                 grads = jax.tree_util.tree_map(lambda g: g / ctx.dp_size, grads)
-            new_p, new_s, info = adamw_update(tcfg.opt, params, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                new_p, new_s, info = adamw_update(tcfg.opt, params, grads,
+                                                  opt_state)
         metrics = {"loss": loss, **info}
         return new_p, new_s, metrics
 

@@ -113,7 +113,13 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--no-cache", action="store_true",
                     help="ignore --cache-dir / $REPRO_CACHE_DIR (cold run)")
     ap.add_argument("--json", metavar="PATH", default=None,
-                    help="write the machine-readable report ('-' = stdout)")
+                    help="write the machine-readable report ('-' = stdout); "
+                         "its timings hold the phase seconds, every span by "
+                         "name (calls, inclusive s, self_s: trace.jaxpr, "
+                         "trace.ir, rules.egraph_build, rules.replay, "
+                         "rules.rewrite, ...) and gc_s, the garbage "
+                         "collector's pauses, with gc_collections by "
+                         "generation")
     ap.add_argument("--inject", metavar="INJECTOR[:INDEX]", default=None,
                     help="inject a bug into the distributed graph first "
                          "(testing/demo; see repro.core.inject). INDEX "

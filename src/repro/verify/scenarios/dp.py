@@ -8,13 +8,12 @@ language); those paths are covered by numerical equivalence tests.
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
+from repro.core.spans import span
 from repro.core.trace import trace_sharded
 from repro.core.verifier import OutputSpec
 from repro.parallel.ctx import ParallelCtx
@@ -53,24 +52,23 @@ def dp_forward_pair(arch: str, cfg, dp: int, batch: int, seq: int,
     replicated, inputs sharded on dim 0, logits sharded on dim 0 — proves
     the model has no improper cross-batch interaction under DP."""
     ctx = ctx if ctx is not None else BuildCtx()
-    t0 = time.perf_counter()
-    mesh, model_s, model_d, param_shapes, pspecs, b, bspecs = _dp_setup(
-        arch, cfg, dp, batch, seq)
+    with span("trace"):
+        mesh, model_s, model_d, param_shapes, pspecs, b, bspecs = _dp_setup(
+            arch, cfg, dp, batch, seq)
 
-    base_fn = lambda p, bb: model_s.forward(p, bb, unroll=True)
-    dist_fn = lambda p, bb: model_d.forward(p, bb, unroll=True)
-    gb, b_in = ctx.trace_base("fwd:dense", base_fn, param_shapes, b,
-                              name=f"{arch}-dp-base")
-    gd, d_in, _ = trace_sharded(
-        dist_fn, mesh, (pspecs, bspecs), P(DP_AXIS),
-        param_shapes, b, name=f"{arch}-dp-dist")
+        base_fn = lambda p, bb: model_s.forward(p, bb, unroll=True)
+        dist_fn = lambda p, bb: model_d.forward(p, bb, unroll=True)
+        gb, b_in = ctx.trace_base("fwd:dense", base_fn, param_shapes, b,
+                                  name=f"{arch}-dp-base")
+        gd, d_in, _ = trace_sharded(
+            dist_fn, mesh, (pspecs, bspecs), P(DP_AXIS),
+            param_shapes, b, name=f"{arch}-dp-dist")
     return GraphPair(
         gb, gd, b_in, d_in,
         input_facts=spec_input_facts(flat_spec_leaves((pspecs, bspecs)),
                                      axis=DP_AXIS),
         output_specs=[OutputSpec(kind="shard", dim=0)],
-        size=dp, axis=DP_AXIS,
-        trace_s=time.perf_counter() - t0, base_cached=ctx.base_cached)
+        size=dp, axis=DP_AXIS, base_cached=ctx.base_cached)
 
 
 @S.scenario("dp-forward", DP_AXIS,
@@ -89,35 +87,35 @@ def dp_grad_pair(arch: str, cfg, dp: int, batch: int, seq: int,
     constants — the mean/`1/dp` rescaling is pure scalar algebra applied
     identically by the trainer on both sides."""
     ctx = ctx if ctx is not None else BuildCtx()
-    t0 = time.perf_counter()
-    mesh, model_s, model_d, param_shapes, pspecs, b, bspecs = _dp_setup(
-        arch, cfg, dp, batch, seq)
+    with span("trace"):
+        mesh, model_s, model_d, param_shapes, pspecs, b, bspecs = _dp_setup(
+            arch, cfg, dp, batch, seq)
 
-    def base_fn(p, bb):
-        return jax.grad(
-            lambda q: model_s.forward(q, bb, unroll=True)
-            .astype(jnp.float32).sum())(p)
+        def base_fn(p, bb):
+            return jax.grad(
+                lambda q: model_s.forward(q, bb, unroll=True)
+                .astype(jnp.float32).sum())(p)
 
-    def dist_fn(p, bb):
-        g = jax.grad(
-            lambda q: model_d.forward(q, bb, unroll=True)
-            .astype(jnp.float32).sum())(p)
-        return jax.tree_util.tree_map(lambda a: jax.lax.psum(a, DP_AXIS), g)
+        def dist_fn(p, bb):
+            g = jax.grad(
+                lambda q: model_d.forward(q, bb, unroll=True)
+                .astype(jnp.float32).sum())(p)
+            return jax.tree_util.tree_map(lambda a: jax.lax.psum(a, DP_AXIS),
+                                          g)
 
-    gb, b_in = ctx.trace_base("grad", base_fn, param_shapes, b,
-                              name=f"{arch}-grad-base")
-    gd, d_in, _ = trace_sharded(
-        dist_fn, mesh, (pspecs, bspecs),
-        jax.tree_util.tree_map(lambda _: P(), param_shapes),
-        param_shapes, b, name=f"{arch}-grad-dist")
+        gb, b_in = ctx.trace_base("grad", base_fn, param_shapes, b,
+                                  name=f"{arch}-grad-base")
+        gd, d_in, _ = trace_sharded(
+            dist_fn, mesh, (pspecs, bspecs),
+            jax.tree_util.tree_map(lambda _: P(), param_shapes),
+            param_shapes, b, name=f"{arch}-grad-dist")
     n_out = len(jax.tree_util.tree_leaves(param_shapes))
     return GraphPair(
         gb, gd, b_in, d_in,
         input_facts=spec_input_facts(flat_spec_leaves((pspecs, bspecs)),
                                      axis=DP_AXIS),
         output_specs=[OutputSpec(kind="dup")] * n_out,
-        size=dp, axis=DP_AXIS,
-        trace_s=time.perf_counter() - t0, base_cached=ctx.base_cached)
+        size=dp, axis=DP_AXIS, base_cached=ctx.base_cached)
 
 
 @S.scenario("dp-grad", DP_AXIS,

@@ -70,7 +70,7 @@ def ep_moe_forward(arch: str, cfg, plan, scen, ctx: BuildCtx) -> GraphPair:
         raise PlanError(
             f"{arch}: {cfg.experts} experts not divisible by ep={ep}")
     pair_fn = lambda c: _ep_forward_parts(arch, c, ep, batch, plan.seq, ctx)
-    parts, trace_s, stamp_s, stamped = stamped_or_full(
+    parts, stamped = stamped_or_full(
         cfg, pair_fn, cfg.block_period, ctx.stamp)
     gb, b_in, gd, d_in, flat_specs = parts
     return GraphPair(
@@ -78,5 +78,4 @@ def ep_moe_forward(arch: str, cfg, plan, scen, ctx: BuildCtx) -> GraphPair:
         input_facts=spec_input_facts(flat_specs, axis=TP_AXIS),
         output_specs=[OutputSpec(kind="dup")],
         size=ep, axis=TP_AXIS,
-        trace_s=trace_s, stamp_s=stamp_s, stamped=stamped,
-        base_cached=ctx.base_cached)
+        stamped=stamped, base_cached=ctx.base_cached)

@@ -13,7 +13,6 @@ surfaces a hit).
 from __future__ import annotations
 
 import dataclasses
-import time
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -22,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core.ir import Graph
+from repro.core.spans import span
 from repro.core.stamp import TRACE_PERIODS, stamp_graph
 from repro.core.trace import LAYER_TAG_STRIDE, trace
 from repro.models import Model
@@ -41,8 +41,6 @@ class GraphPair:
     output_specs: list
     size: int
     axis: str
-    trace_s: float = 0.0
-    stamp_s: float = 0.0
     stamped: bool = False
     base_cached: bool = False  # base trace served from the shared cache
     # every axis the dist program's mesh declares (empty = just ``axis``);
@@ -178,35 +176,33 @@ def stamped_parts(cfg, pair_fn, periods_per_block: int):
 
     ``periods_per_block``: layer tags per period region (block_period for
     forward traces whose periods span P layer scopes; 1 for decode traces
-    whose period is one outer block scope).  Returns ``(parts, stamp_s)``."""
+    whose period is one outer block scope).  The work is spanned ``trace``
+    and ``stamp`` in the verdict open, if any (:mod:`repro.core.spans`)."""
     total = cfg.n_layers // cfg.block_period
     if total <= TRACE_PERIODS:
-        return None, 0.0
+        return None
     cfg_t = dataclasses.replace(
         cfg, n_layers=TRACE_PERIODS * cfg.block_period)
-    gb, b_in, gd, d_in, flat_specs = pair_fn(cfg_t)
-    t0 = time.perf_counter()
-    stride = LAYER_TAG_STRIDE * periods_per_block
-    sb = stamp_graph(gb, total, lambda t: t // stride)
-    if sb is None:
-        return None, time.perf_counter() - t0
-    sd = stamp_graph(gd, total, lambda t: t // stride)
+    with span("trace"):
+        gb, b_in, gd, d_in, flat_specs = pair_fn(cfg_t)
+    with span("stamp"):
+        stride = LAYER_TAG_STRIDE * periods_per_block
+        sb = stamp_graph(gb, total, lambda t: t // stride)
+        sd = None if sb is None else stamp_graph(gd, total,
+                                                 lambda t: t // stride)
     if sd is None:
-        return None, time.perf_counter() - t0
-    return (sb, b_in, sd, d_in, flat_specs), time.perf_counter() - t0
+        return None
+    return sb, b_in, sd, d_in, flat_specs
 
 
 def stamped_or_full(cfg, pair_fn, periods_per_block: int, stamp: bool):
-    """The standard stamped-with-fallback build: returns
-    ``(parts, trace_s, stamp_s, stamped)`` timed like the legacy builders."""
-    t0 = time.perf_counter()
-    parts, stamp_s = (stamped_parts(cfg, pair_fn, periods_per_block)
-                      if stamp else (None, 0.0))
-    stamped = parts is not None
-    if parts is None:
-        parts = pair_fn(cfg)
-    trace_s = time.perf_counter() - t0 - stamp_s
-    return parts, trace_s, stamp_s, stamped
+    """The standard stamped-with-fallback build: returns ``(parts,
+    stamped)``, with the full trace of the fallback spanned ``trace``."""
+    parts = stamped_parts(cfg, pair_fn, periods_per_block) if stamp else None
+    if parts is not None:
+        return parts, True
+    with span("trace"):
+        return pair_fn(cfg), False
 
 
 def flat_spec_leaves(specs) -> list:

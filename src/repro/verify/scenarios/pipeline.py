@@ -4,13 +4,12 @@ isolation.  Stage boundaries are replicated hidden states — exactly what
 equivalence composes to whole-pipeline equivalence."""
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
+from repro.core.spans import span
 from repro.core.trace import trace_sharded
 from repro.core.verifier import OutputSpec
 from repro.models.model import _tree_index
@@ -44,55 +43,57 @@ def stage_pair(arch: str, cfg, tp: int, stg: int, stages: int,
     lo, hi = stg * per_stage, (stg + 1) * per_stage
     first, last = stg == 0, stg == stages - 1
 
-    t0 = time.perf_counter()
-    mesh = AbstractMesh((tp,), (TP_AXIS,))
-    pctx = ParallelCtx(tp_axis=TP_AXIS, tp_size=tp, ep_axis=TP_AXIS, ep_size=tp)
-    model_s, model_d, param_shapes = model_pair(cfg, pctx)
-    pspecs = verify_pspecs(param_shapes, cfg)
-    b, seq = batch_avals(cfg, model_s, batch, seq)
-    Pnum = cfg.block_period
+    with span("trace"):
+        mesh = AbstractMesh((tp,), (TP_AXIS,))
+        pctx = ParallelCtx(tp_axis=TP_AXIS, tp_size=tp, ep_axis=TP_AXIS,
+                           ep_size=tp)
+        model_s, model_d, param_shapes = model_pair(cfg, pctx)
+        pspecs = verify_pspecs(param_shapes, cfg)
+        b, seq = batch_avals(cfg, model_s, batch, seq)
+        Pnum = cfg.block_period
 
-    def stage_fn(model):
-        def run(params, x_or_batch):
-            if first:
-                x = model._inputs_to_hidden(params, x_or_batch)
-            else:
-                x = x_or_batch
-            positions = jnp.arange(seq)
-            for li in range(lo, hi):
-                with jax.named_scope(f"layer{li}"):
-                    lp = _tree_index(params["blocks"][li % Pnum], li // Pnum)
-                    x = model._layer_fwd(lp, x, positions, li % Pnum, unroll=True)
-            if last:
-                x = model.ctx.sp_exit(x)
-                x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
-                return model._head(params, x)
-            return x
+        def stage_fn(model):
+            def run(params, x_or_batch):
+                if first:
+                    x = model._inputs_to_hidden(params, x_or_batch)
+                else:
+                    x = x_or_batch
+                positions = jnp.arange(seq)
+                for li in range(lo, hi):
+                    with jax.named_scope(f"layer{li}"):
+                        lp = _tree_index(params["blocks"][li % Pnum], li // Pnum)
+                        x = model._layer_fwd(lp, x, positions, li % Pnum,
+                                             unroll=True)
+                if last:
+                    x = model.ctx.sp_exit(x)
+                    x = rmsnorm(params["ln_f"], x, cfg.norm_eps)
+                    return model._head(params, x)
+                return x
 
-        return run
+            return run
 
-    if first:
-        x_aval = b
-        xspec = jax.tree_util.tree_map(lambda _: P(), b)
-    else:
-        x_aval = jax.ShapeDtypeStruct((batch, seq, cfg.d_model), model_s.dtype)
-        xspec = P()
-    out_spec = P(None, None, TP_AXIS) if last else P()
+        if first:
+            x_aval = b
+            xspec = jax.tree_util.tree_map(lambda _: P(), b)
+        else:
+            x_aval = jax.ShapeDtypeStruct((batch, seq, cfg.d_model),
+                                          model_s.dtype)
+            xspec = P()
+        out_spec = P(None, None, TP_AXIS) if last else P()
 
-    gb, b_in = ctx.trace_base(f"stage{stg}:{stages}", stage_fn(model_s),
-                              param_shapes, x_aval,
-                              name=f"{arch}-stage{stg}-base")
-    gd, d_in, _ = trace_sharded(
-        stage_fn(model_d), mesh, (pspecs, xspec), out_spec,
-        param_shapes, x_aval, name=f"{arch}-stage{stg}-dist")
+        gb, b_in = ctx.trace_base(f"stage{stg}:{stages}", stage_fn(model_s),
+                                  param_shapes, x_aval,
+                                  name=f"{arch}-stage{stg}-base")
+        gd, d_in, _ = trace_sharded(
+            stage_fn(model_d), mesh, (pspecs, xspec), out_spec,
+            param_shapes, x_aval, name=f"{arch}-stage{stg}-dist")
     return GraphPair(
         gb, gd, b_in, d_in,
         input_facts=spec_input_facts(flat_spec_leaves((pspecs, xspec)),
                                      axis=TP_AXIS),
         output_specs=[OutputSpec(kind="shard", dim=2) if last
                       else OutputSpec(kind="dup")],
-        size=tp, axis=TP_AXIS,
-        trace_s=time.perf_counter() - t0, base_cached=ctx.base_cached)
+        size=tp, axis=TP_AXIS, base_cached=ctx.base_cached)
 
 
 @S.scenario("stage", TP_AXIS,

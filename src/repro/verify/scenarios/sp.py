@@ -37,7 +37,7 @@ def sp_forward(arch: str, cfg, plan, scen, ctx: BuildCtx) -> GraphPair:
             f"by tp={tp}")
     pair_fn = lambda c: _tp_forward_parts(arch, c, tp, batch, plan.seq, ctx,
                                           sp=True)
-    parts, trace_s, stamp_s, stamped = stamped_or_full(
+    parts, stamped = stamped_or_full(
         cfg, pair_fn, cfg.block_period, ctx.stamp)
     gb, b_in, gd, d_in, flat_specs = parts
     return GraphPair(
@@ -45,5 +45,4 @@ def sp_forward(arch: str, cfg, plan, scen, ctx: BuildCtx) -> GraphPair:
         input_facts=spec_input_facts(flat_specs, axis=TP_AXIS),
         output_specs=[OutputSpec(kind="shard", dim=2)],
         size=tp, axis=TP_AXIS,
-        trace_s=trace_s, stamp_s=stamp_s, stamped=stamped,
-        base_cached=ctx.base_cached)
+        stamped=stamped, base_cached=ctx.base_cached)

@@ -60,7 +60,7 @@ def tp_forward_pair(arch: str, cfg, tp: int, batch: int, seq: int,
                     stamp: bool = True, ctx: BuildCtx = None) -> GraphPair:
     ctx = ctx if ctx is not None else BuildCtx(stamp=stamp)
     pair_fn = lambda c: _tp_forward_parts(arch, c, tp, batch, seq, ctx)
-    parts, trace_s, stamp_s, stamped = stamped_or_full(
+    parts, stamped = stamped_or_full(
         cfg, pair_fn, cfg.block_period, ctx.stamp)
     gb, b_in, gd, d_in, flat_specs = parts
     return GraphPair(
@@ -68,8 +68,7 @@ def tp_forward_pair(arch: str, cfg, tp: int, batch: int, seq: int,
         input_facts=spec_input_facts(flat_specs, axis=TP_AXIS),
         output_specs=[OutputSpec(kind="shard", dim=2)],
         size=tp, axis=TP_AXIS,
-        trace_s=trace_s, stamp_s=stamp_s, stamped=stamped,
-        base_cached=ctx.base_cached)
+        stamped=stamped, base_cached=ctx.base_cached)
 
 
 @S.scenario("tp-forward", TP_AXIS,
@@ -114,7 +113,7 @@ def tp_decode_pair(arch: str, cfg, tp: int, batch: int, max_len: int,
     ctx = ctx if ctx is not None else BuildCtx(stamp=stamp)
     # one decode period = one outer block scope (P sub-layers)
     pair_fn = lambda c: _tp_decode_parts(arch, c, tp, batch, max_len, ctx)
-    parts, trace_s, stamp_s, stamped = stamped_or_full(
+    parts, stamped = stamped_or_full(
         cfg, pair_fn, 1, ctx.stamp)
     gb, b_in, gd, d_in, (flat_specs, cspecs) = parts
 
@@ -128,8 +127,7 @@ def tp_decode_pair(arch: str, cfg, tp: int, batch: int, max_len: int,
         input_facts=spec_input_facts(flat_specs, axis=TP_AXIS),
         output_specs=out_specs,
         size=tp, axis=TP_AXIS,
-        trace_s=trace_s, stamp_s=stamp_s, stamped=stamped,
-        base_cached=ctx.base_cached)
+        stamped=stamped, base_cached=ctx.base_cached)
 
 
 @S.scenario("tp-decode", TP_AXIS,

@@ -24,15 +24,14 @@ from __future__ import annotations
 import concurrent.futures as _fut
 import dataclasses
 import hashlib
-import time
 from dataclasses import replace
 from typing import Optional
 
 from repro.configs import get_config
 from repro.core.ir import diff_graphs
 from repro.core.partition import TemplateCache, delta_template_cache
-from repro.core.report import (CacheStats, PhaseTimings, Report, RuleProfiler,
-                               rank_bug_sites)
+from repro.core.report import CacheStats, PhaseTimings, Report, rank_bug_sites
+from repro.core.spans import verdict
 from repro.core.verifier import VerifyOptions, resolve_backend, verify_graphs
 
 from .plan import Plan, Scenario
@@ -168,15 +167,20 @@ class Session:
         plan = plan if plan is not None else Plan(**plan_kw)
         options = options or self.options or VerifyOptions()
         cfg_h = _cfg_hash(get_config(arch, smoke=plan.smoke))
-        t0 = time.perf_counter()
         results: list[tuple[Scenario, Report]] = []
-        for scen in plan.scenarios():
-            results.append(
-                (scen, self._run_scenario(arch, cfg_h, plan, scen, options,
-                                          mutate_dist, mutate_pure,
-                                          lint=lint)))
+        with verdict() as rec:
+            for scen in plan.scenarios():
+                mark = rec.mark()
+                rep = self._run_scenario(arch, cfg_h, plan, scen, options,
+                                         mutate_dist, mutate_pure, lint=lint)
+                # the scenario's whole window: trace and stamp (absent on a
+                # graph-cache hit), rules and localize
+                rep.timings = replace(rec.timings(mark),
+                                      profile=rep.timings.profile)
+                rep.elapsed_s = rec.elapsed(mark)
+                results.append((scen, rep))
         report = _merge(arch, plan, results)
-        report.elapsed_s = time.perf_counter() - t0
+        report.elapsed_s = rec.root.seconds
         return report
 
     def _run_scenario(self, arch: str, cfg_h: str, plan: Plan, scen: Scenario,
@@ -235,9 +239,6 @@ class Session:
                         delta_nodes = len(delta.changed)
         else:
             cache = self._templates.setdefault(key, TemplateCache())
-        timings = PhaseTimings(
-            trace_s=0.0 if cached else pair.trace_s,
-            stamp_s=0.0 if cached else pair.stamp_s)
         opts = replace(options, axis=pair.axis)
         rep = verify_graphs(
             pair.base, dist,
@@ -249,7 +250,6 @@ class Session:
             options=opts,
             cache=cache,
             pool=self._get_pool(options),
-            timings=timings,
         )
         rep.cache.trace_cached = cached
         rep.cache.base_trace_cached = pair.base_cached
@@ -340,14 +340,7 @@ def _merge(arch: str, plan: Plan, results) -> Report:
             memo=None,
             unverified_count=sum(r.unverified_count for r in reps),
             rule_invocations=sum(r.rule_invocations for r in reps),
-            timings=PhaseTimings(
-                trace_s=sum(r.timings.trace_s for r in reps),
-                stamp_s=sum(r.timings.stamp_s for r in reps),
-                rules_s=sum(r.timings.rules_s for r in reps),
-                localize_s=sum(r.timings.localize_s for r in reps),
-                profile=RuleProfiler.merge_summaries(
-                    [r.timings.profile for r in reps]),
-            ),
+            timings=PhaseTimings.merge([r.timings for r in reps]),
             cache=CacheStats(
                 trace_cached=all(r.cache.trace_cached for r in reps),
                 base_trace_cached=any(r.cache.base_trace_cached for r in reps),

@@ -19,8 +19,7 @@ from repro.verify.specs import spec_input_facts as _spec_input_facts
 
 
 def _stamped_pair(cfg, pair_fn, periods_per_block):
-    parts, _ = _stamped_parts(cfg, pair_fn, periods_per_block)
-    return parts
+    return _stamped_parts(cfg, pair_fn, periods_per_block)
 from repro.core.partition import partition_layers
 from repro.core.rules import Propagator, WorklistEngine
 from repro.core.stamp import TRACE_PERIODS, stamp_graph
