@@ -5,7 +5,8 @@ repro.kernels.ssd_scan mirrors the chunk-parallel structure on TPU):
 
   within chunk:  Y_diag = (C B^T ⊙ L) · (dt x)        (attention-like matmuls)
   chunk states:  S_c    = Σ_k decay_to_end · dt_k B_k x_k^T
-  across chunks: S_c   <- S_{c-1} · Π decay + S_c      (short scan over chunks)
+  across chunks: S_c   <- S_{c-1} · Π decay + S_c      (one decay-matrix
+                 contraction over chunks, no loop; written out for verification)
   offset:        Y_off  = decay_from_start · C S_{c-1}
 
 TP shards the SSD heads over ``model``; B/C projections are replicated
@@ -67,6 +68,45 @@ def _segsum(dA):
     return jnp.where(mask, diff, -jnp.inf)
 
 
+def _chunk_decay(clog, rows: int):
+    """Decay between chunks: clog (B,H,nc), each chunk's summed log-decay ->
+    (B,H,rows,nc+1).  Entry [c, 0] takes the initial state, entry [c, z+1]
+    the state chunk z leaves, to the start of chunk c: 0 for z >= c."""
+    return jnp.exp(_segsum(jnp.pad(clog, ((0, 0), (0, 0), (1, 0)))))[:, :, :rows]
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _carry_states(clog, states, rows: int):
+    """The state entering each of ``rows`` chunks, from a zero initial state:
+    out_c = sum_{z<c} exp(clog_{z+1..c-1}) states_z, one contraction over
+    chunks (Listing 1 of arXiv:2405.21060).  states (B,nc,H,P,N) -> out
+    (B,rows,H,P,N).  HIGHEST keeps the f32 of the recurrence: the TPU's
+    default would round both operands to bfloat16."""
+    return jnp.einsum("bhcz,bzhpn->bchpn", _chunk_decay(clog, rows)[..., 1:], states,
+                      precision=lax.Precision.HIGHEST)
+
+
+def _carry_states_fwd(clog, states, rows):
+    out = _carry_states(clog, states, rows)
+    return out, (clog, out)
+
+
+def _carry_states_bwd(rows, res, g):
+    # states_z enters the recurrence at the end of chunk z, so its gradient is
+    # that of the state there, and clog_z scales the state entering chunk z:
+    # d clog_z = exp(clog_z) <d states_z, out_z>.  That replaces the second
+    # contraction over chunks autodiff would make for the decay matrix.
+    clog, out = res
+    nc = clog.shape[-1]
+    g_states = jnp.einsum("bhcz,bchpn->bzhpn", _chunk_decay(clog, rows)[..., 1:], g,
+                          precision=lax.Precision.HIGHEST)
+    g_clog = jnp.exp(clog) * jnp.sum(g_states * out[:, :nc], axis=(3, 4)).transpose(0, 2, 1)
+    return g_clog, g_states
+
+
+_carry_states.defvjp(_carry_states_fwd, _carry_states_bwd)
+
+
 def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None, return_state: bool = False,
                 unroll: bool = False):
     """SSD scan.  x: (B,S,H,P), dt: (B,S,H) (post-softplus), A: (H,) negative,
@@ -98,25 +138,30 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None, return_state: boo
     decay_end = jnp.exp(cum[:, :, -1:, :] - cum)  # (B,nc,Q,H)
     states = jnp.einsum("bckn,bckh,bckhp->bchpn", Bc.astype(f32), decay_end * dtc, xc.astype(f32))
 
-    # inter-chunk recurrence
-    chunk_decay = jnp.exp(cum[:, :, -1, :])  # (B,nc,H)
-    s0 = jnp.zeros((Bsz, H, P, N), f32) if init_state is None else init_state.astype(f32)
+    # inter-chunk recurrence S_c = exp(clog_c) S_{c-1} + states_c; prev
+    # (B,nc,H,P,N) is the state entering each chunk
+    if unroll:  # verification traces: one step per chunk, written out
+        chunk_decay = jnp.exp(cum[:, :, -1, :])  # (B,nc,H)
+        s0 = jnp.zeros((Bsz, H, P, N), f32) if init_state is None else init_state.astype(f32)
 
-    def body(s_prev, inp):
-        st, dec = inp  # (B,H,P,N), (B,H)
-        s_new = s_prev * dec[:, :, None, None] + st
-        return s_new, s_prev
+        def body(s_prev, inp):
+            st, dec = inp  # (B,H,P,N), (B,H)
+            s_new = s_prev * dec[:, :, None, None] + st
+            return s_new, s_prev
 
-    if unroll:  # verification traces: no scan nodes
         s_cur, prevs = s0, []
         for ci in range(nc):
             s_cur, pv = body(s_cur, (states[:, ci], chunk_decay[:, ci]))
             prevs.append(pv)
-        sc, prev = s_cur, jnp.stack(prevs)
-    else:
-        sc, prev = lax.scan(body, s0, (states.transpose(1, 0, 2, 3, 4),
-                                       chunk_decay.transpose(1, 0, 2)))
-    prev = prev.transpose(1, 0, 2, 3, 4)  # (B,nc,H,P,N) state entering each chunk
+        sc, prev = s_cur, jnp.stack(prevs).transpose(1, 0, 2, 3, 4)
+    else:  # closed form, no loop; row nc of ``out`` is the final state
+        rows = nc + 1 if return_state else nc
+        clog = cum[:, :, -1, :].transpose(0, 2, 1)  # (B,H,nc) log-decay of each chunk
+        out = _carry_states(clog, states, rows)
+        if init_state is not None:
+            carry0 = _chunk_decay(clog, rows)[..., 0].transpose(0, 2, 1)  # (B,rows,H)
+            out = out + carry0[..., None, None] * init_state.astype(f32)[:, None]
+        prev, sc = out[:, :nc], (out[:, nc] if return_state else None)
 
     decay_start = jnp.exp(cum)  # (B,nc,Q,H)
     y_off = jnp.einsum("bcqn,bchpn,bcqh->bcqhp", Cc.astype(f32), prev, decay_start)
@@ -131,7 +176,8 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int, init_state=None, return_state: boo
 @partial(jax.custom_vjp, nondiff_argnums=(5,))
 def ssd_pallas(x, dt, A, Bm, Cm, chunk: int):
     """SSD scan through the Pallas kernel (Mosaic; TPU only).  The backward
-    pass is the vjp of ``ssd_chunked``, recomputed from the saved inputs."""
+    pass is the vjp of ``ssd_chunked``, recomputed from the saved inputs; it
+    is loop-free, its inter-chunk recurrence one contraction over chunks."""
     # imported here: loading Pallas adds seconds to every cold verify
     from repro.kernels import ops as kops
 

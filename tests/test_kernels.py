@@ -116,29 +116,101 @@ def interpret_kernels(monkeypatch):
     monkeypatch.setattr(ops, "ssd_scan", functools.partial(ops.ssd_scan, interpret=True))
 
 
-def _assert_vjp_matches(fn, ref_fn, args, tol):
-    """Same forward, and the same gradients of a random projection."""
+def _assert_vjp_matches(fn, ref_fn, args, tol, scaled=False):
+    """Same forward, and the same gradients of a random projection.  With
+    ``scaled`` the absolute tolerance is ``tol`` times the reference's largest
+    magnitude, for gradients that reach 1e5."""
     out, vjp = jax.vjp(fn, *args)
     ref, ref_vjp = jax.vjp(ref_fn, *args)
-    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
-                               rtol=tol, atol=tol)
     ct = jax.random.normal(jax.random.PRNGKey(11), out.shape, out.dtype)
-    for g, r in zip(vjp(ct), ref_vjp(ct)):
-        np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(r, np.float32),
-                                   rtol=tol, atol=tol)
+    for g, r in zip((out, *vjp(ct)), (ref, *ref_vjp(ct))):
+        g, r = np.asarray(g, np.float32), np.asarray(r, np.float32)
+        atol = tol * np.abs(r).max() if scaled else tol
+        np.testing.assert_allclose(g, r, rtol=tol, atol=atol)
 
 
-def test_ssd_pallas_vjp_matches_chunked(interpret_kernels):
+# one chunk, two chunks, and 32 chunks at the full state width; decays weak
+# enough that the state entering a chunk carries over several chunks
+SSD_GRAD_SHAPES = [
+    (1, 64, 2, 16, 8, 64),
+    (1, 128, 2, 32, 16, 64),
+    (1, 1024, 2, 32, 128, 32),
+]
+
+
+def _ssd_args(B, S, H, P, N, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return (jax.random.normal(ks[0], (B, S, H, P)),
+            jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))),
+            -0.02 * jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5),
+            jax.random.normal(ks[3], (B, S, N)), jax.random.normal(ks[4], (B, S, N)))
+
+
+@pytest.mark.parametrize("B,S,H,P,N,chunk", SSD_GRAD_SHAPES)
+def test_ssd_pallas_vjp_matches_chunked(interpret_kernels, B, S, H, P, N, chunk):
     from repro.models.ssm import ssd_pallas
 
-    ks = jax.random.split(KEY, 5)
-    B, S, H, P, N, chunk = 1, 128, 2, 32, 16, 64
-    args = (jax.random.normal(ks[0], (B, S, H, P)),
-            jax.nn.softplus(jax.random.normal(ks[1], (B, S, H))),
-            -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5),
-            jax.random.normal(ks[3], (B, S, N)), jax.random.normal(ks[4], (B, S, N)))
     _assert_vjp_matches(lambda *a: ssd_pallas(*a, chunk),
-                        lambda *a: ssd_chunked(*a, chunk), args, 5e-4)
+                        lambda *a: ssd_chunked(*a, chunk), _ssd_args(B, S, H, P, N),
+                        5e-4)
+
+
+@pytest.mark.parametrize("B,S,H,P,N,chunk", SSD_GRAD_SHAPES)
+def test_ssd_chunked_vjp_matches_recurrence(B, S, H, P, N, chunk):
+    """The chunked form and its gradients against the literal recurrence,
+    a different algorithm (one step per token)."""
+    _assert_vjp_matches(lambda *a: ssd_chunked(*a, chunk), ssd_ref,
+                        _ssd_args(B, S, H, P, N), 2e-5, scaled=True)
+
+
+def _primitives(jaxpr) -> set:
+    """Names of every primitive in a jaxpr and in the jaxprs nested in it."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+def test_ssd_chunked_has_no_chunk_loop():
+    """The deployed form, and so the Pallas path's backward, holds no loop
+    over chunks: not in the forward and not in its vjp."""
+    args = _ssd_args(1, 1024, 2, 32, 128)
+    fwd = lambda *a: ssd_chunked(*a, 32)
+
+    def vjp(*a):
+        out, pullback = jax.vjp(fwd, *a)
+        return pullback(out)
+
+    for fn in (fwd, vjp):
+        prims = _primitives(jax.make_jaxpr(fn)(*args).jaxpr)
+        assert "dot_general" in prims
+        assert not prims & {"scan", "while"}, prims
+
+
+@pytest.mark.parametrize("with_init", [False, True])
+def test_ssd_chunked_unroll_matches_contraction(with_init):
+    """The unrolled form that verification traces and the deployed closed
+    form give the same output and final state, and the same gradients."""
+    B, S, H, P, N, chunk = 2, 512, 3, 16, 32, 32
+    args = _ssd_args(B, S, H, P, N, seed=1)
+    if with_init:
+        args += (jax.random.normal(jax.random.PRNGKey(5), (B, H, P, N)),)
+
+    def run(unroll):
+        return lambda x, dt, A, Bm, Cm, s0=None: ssd_chunked(
+            x, dt, A, Bm, Cm, chunk, init_state=s0, return_state=True, unroll=unroll)
+
+    (y, s), vjp = jax.vjp(run(False), *args)
+    (y_u, s_u), vjp_u = jax.vjp(run(True), *args)
+    cts = (jax.random.normal(jax.random.PRNGKey(6), y.shape),
+           jax.random.normal(jax.random.PRNGKey(7), s.shape))
+    for a, b in zip((y, s, *vjp(cts)), (y_u, s_u, *vjp_u(cts))):
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=2e-5, atol=2e-5 * np.abs(b).max())
+    np.testing.assert_array_equal(np.asarray(ssd_chunked(*args[:5], chunk, *args[5:])),
+                                  np.asarray(y))
 
 
 @pytest.mark.parametrize("causal", [True, False])
