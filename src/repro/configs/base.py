@@ -45,7 +45,6 @@ class ArchConfig:
     shared_expert_ff: int = 0
     moe_period: int = 1  # layer l uses MoE iff n_experts>0 and l % moe_period == moe_offset
     moe_offset: int = 0
-    capacity_factor: float = 1.25
     # hybrid / SSM
     attn_period: int = 0  # 0 = attention everywhere; k>0 -> attention iff l%k==attn_offset
     attn_offset: int = 0
@@ -62,6 +61,11 @@ class ArchConfig:
     frontend_len: int = 0  # number of prefix embeddings
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
+    # muP-style scalings (Granite); the defaults leave the model unscaled
+    embedding_multiplier: float = 1.0  # embedding lookup times this
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(head dim)
+    residual_multiplier: float = 1.0  # each block's branch times this
+    logits_scaling: float = 1.0  # logits divided by this
     # padding applied for TP/EP divisibility (documented in DESIGN.md §4.1)
     n_heads_padded: int = 0
     n_kv_heads_padded: int = 0
@@ -72,6 +76,10 @@ class ArchConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def attn_scale(self) -> float:
+        return self.attention_multiplier or self.hd ** -0.5
 
     @property
     def heads(self) -> int:

@@ -1,4 +1,7 @@
-"""Granite-MoE-3B-a800m [hf:ibm-granite; hf-tier] — MoE 40e top-8 per the structured assignment spec (inline note says 32e; spec wins, see DESIGN.md §9). Experts padded 40->48 for 16-way EP."""
+"""Granite-3.0-3B-A800M [hf:ibm-granite/granite-3.0-3b-a800m-instruct, config.json]:
+MoE 40 experts top-8, GQA 24/8 heads, tied embeddings, RMSNorm eps 1e-6, and
+Granite's four multipliers (embedding, attention, residual, logits).  Heads
+are padded 24->32, kv heads 8->16 and experts 40->48 for 16-way TP/EP plans."""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -19,6 +22,12 @@ CONFIG = ArchConfig(
     n_heads_padded=32,
     n_kv_heads_padded=16,
     vocab_padded=49168,
+    tie_embeddings=True,
+    norm_eps=1e-6,
+    embedding_multiplier=12.0,
+    attention_multiplier=0.015625,
+    residual_multiplier=0.22,
+    logits_scaling=6.0,
 )
 
 SMOKE = ArchConfig(
@@ -36,4 +45,10 @@ SMOKE = ArchConfig(
     top_k=2,
     d_ff_expert=64,
     mlp_act='swiglu',
+    tie_embeddings=True,
+    norm_eps=1e-6,
+    embedding_multiplier=12.0,
+    attention_multiplier=0.0625,
+    residual_multiplier=0.22,
+    logits_scaling=6.0,
 )

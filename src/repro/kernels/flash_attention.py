@@ -68,8 +68,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False):
-    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D)."""
+                    block_k: int = 128, interpret: bool = False,
+                    scale: float | None = None):
+    """q: (B, Hq, Sq, D); k/v: (B, Hkv, Sk, D) -> (B, Hq, Sq, D); the
+    softmax scale of q·k is ``scale`` (default 1/sqrt(D))."""
     B, Hq, Sq, D = q.shape
     Hkv, Sk = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -86,7 +88,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     n_q = (Sq + pad_q) // bq
     n_k = (Sk + pad_k) // bk
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, bq=bq, bk=bk, n_k=n_k,

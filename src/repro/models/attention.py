@@ -51,18 +51,20 @@ def _split_heads(x, n_heads: int):
 def chunked_attention(
     q, k, v, *, causal: bool, q_offset=0, k_offset=0, kv_len: Optional[jnp.ndarray] = None,
     chunk: int = 1024, with_stats: bool = False, unroll: bool = False,
+    scale: Optional[float] = None,
 ):
     """Flash-style online-softmax attention in pure jnp.
 
     q: (B, Hq, Sq, hd); k/v: (B, Hkv, Sk, hd) with Hq = G * Hkv.
     ``kv_len``: optional dynamic valid length (decode masking).
     ``with_stats``: also return (m, l) running stats for cross-device merges.
+    ``scale``: softmax scale of q·k (default 1/sqrt(hd)).
     """
     B, Hq, Sq, hd = q.shape
     _, Hkv, Sk, _ = k.shape
     G = Hq // Hkv
     qg = q.reshape(B, Hkv, G, Sq, hd)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     chunk = min(chunk, Sk)
     n_chunks = (Sk + chunk - 1) // chunk
     pad = n_chunks * chunk - Sk
@@ -119,23 +121,24 @@ def chunked_attention(
     return out
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3,))
-def flash_pallas(q, k, v, causal: bool):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def flash_pallas(q, k, v, causal: bool, scale: float):
     """Attention through the Pallas flash kernel (Mosaic; TPU only).  The
     backward pass is the vjp of ``chunked_attention``, recomputed from the
     saved inputs."""
     # imported here: loading Pallas adds seconds to every cold verify
     from repro.kernels import ops as kops
 
-    return kops.flash_attention(q, k, v, causal=causal)
+    return kops.flash_attention(q, k, v, causal=causal, scale=scale)
 
 
-def _flash_pallas_fwd(q, k, v, causal):
-    return flash_pallas(q, k, v, causal), (q, k, v)
+def _flash_pallas_fwd(q, k, v, causal, scale):
+    return flash_pallas(q, k, v, causal, scale), (q, k, v)
 
 
-def _flash_pallas_bwd(causal, res, g):
-    _, vjp = jax.vjp(lambda *a: chunked_attention(*a, causal=causal), *res)
+def _flash_pallas_bwd(causal, scale, res, g):
+    _, vjp = jax.vjp(lambda *a: chunked_attention(*a, causal=causal, scale=scale),
+                     *res)
     return vjp(g)
 
 
@@ -162,9 +165,10 @@ def attn_fwd(cfg, ctx: ParallelCtx, p, x, positions, *, impl: str = "reference",
     q = apply_rope(q, positions, cfg.rope_fraction, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_fraction, cfg.rope_theta)
     if impl == "pallas":
-        out = flash_pallas(q, k, v, cfg.causal)
+        out = flash_pallas(q, k, v, cfg.causal, cfg.attn_scale)
     else:
-        out = chunked_attention(q, k, v, causal=cfg.causal, unroll=unroll)
+        out = chunked_attention(q, k, v, causal=cfg.causal, unroll=unroll,
+                                scale=cfg.attn_scale)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, Hq_loc * hd)
     y = linear(p["wo"], out)  # row-parallel -> partial sum across tp
     return ctx.sp_enter(y)
@@ -220,7 +224,7 @@ def attn_decode(cfg, ctx: ParallelCtx, p, x, cache, position, *, unroll: bool = 
         kv_len = position + 1
         acc, m, denom = chunked_attention(
             q, new_k, new_v, causal=False, q_offset=0, k_offset=k_off,
-            kv_len=kv_len, with_stats=True, unroll=unroll)
+            kv_len=kv_len, with_stats=True, unroll=unroll, scale=cfg.attn_scale)
         # flash-decode merge across shards (verified pattern, paper §7.1)
         m_g = ctx.pmax_cp(m)
         corr = jnp.exp(m - m_g)
@@ -232,7 +236,7 @@ def attn_decode(cfg, ctx: ParallelCtx, p, x, cache, position, *, unroll: bool = 
         new_k = lax.dynamic_update_slice_in_dim(cache["k"], knew, position, axis=2)
         new_v = lax.dynamic_update_slice_in_dim(cache["v"], vnew, position, axis=2)
         out = chunked_attention(q, new_k, new_v, causal=False, kv_len=position + 1,
-                                unroll=unroll)
+                                unroll=unroll, scale=cfg.attn_scale)
     out = out.transpose(0, 2, 1, 3).reshape(B, 1, Hq_loc * hd)
     y = linear(p["wo"], out)
     return ctx.sp_enter(y), {"k": new_k, "v": new_v}

@@ -1,11 +1,13 @@
-"""Feed-forward blocks: dense GLU/GELU MLPs and capacity-based top-k MoE with
+"""Feed-forward blocks: dense GLU/GELU MLPs and a dropless top-k MoE with
 expert parallelism.
 
-MoE dispatch is sort-free (cumsum positions + scatter into per-expert
-capacity buffers), deterministic-shape, and EP-aware: each rank materializes
-only its local experts' buffers; the per-token combine is a partial sum
-discharged by one psum over the expert axis.  Padded experts (e.g. granite
-40 -> 48) are masked out in the router.
+The execution MoE (``moe_fwd``) groups the routed (token, slot) pairs of the
+experts a rank holds by expert, with static shapes and no sort (cumsum
+ranks), runs them through grouped matrix products and combines the
+per-token partial sum with one psum over the expert axis.  No token is
+dropped.  ``moe_dense_fwd`` and ``moe_ep_fwd`` are the verifier's dense
+formulations.  Padded experts (e.g. granite 40 -> 48) are masked out in the
+router.
 """
 from __future__ import annotations
 
@@ -63,64 +65,153 @@ def moe_init(key, cfg, *, stacked: tuple = (), dtype=jnp.bfloat16):
     return p
 
 
-def moe_capacity(cfg, tokens: int) -> int:
-    return int(math.ceil(tokens * cfg.top_k / cfg.experts * cfg.capacity_factor))
+# rows of a grouped-matmul tile on the TPU; the jnp path needs no tiles
+GMM_TILE = 256
 
 
-def moe_fwd(cfg, ctx: ParallelCtx, p, x):
-    """Top-k routed MoE.  x: (B, S, D) (replicated across the expert axis).
-
-    Returns the combined expert output (+ shared expert), a replicated tensor
-    after the expert-axis psum.
-    """
-    B, S, D = x.shape
-    T = B * S
+def _route(cfg, p, xf):
+    """Softmax over the real experts, top-k, renormalised: (T, K) weights in
+    float32 and expert ids.  Equals Granite's softmax over the top-k logits."""
     E = cfg.experts
-    K = cfg.top_k
-    C = moe_capacity(cfg, T)
-    xf = x.reshape(T, D)
-
     logits = (xf.astype(jnp.float32) @ p["router"]["w"]).astype(jnp.float32)
     if cfg.n_experts_padded and cfg.n_experts_padded > cfg.n_experts:
         pad_mask = jnp.arange(E) >= cfg.n_experts
         logits = jnp.where(pad_mask[None, :], -1e30, logits)
     probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = jax.lax.top_k(probs, K)  # (T, K)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)  # renormalize top-k
+    w, idx = jax.lax.top_k(probs, cfg.top_k)
+    return w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9), idx
 
-    # flatten (token, slot) pairs and compute per-expert positions
-    eid = idx.reshape(T * K)
-    wflat = w.reshape(T * K).astype(x.dtype)
-    onehot = jax.nn.one_hot(eid, E, dtype=jnp.int32)  # (TK, E)
-    pos = (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1  # (TK,)
-    keep = pos < C
 
-    # expert parallelism: this rank owns experts [r*E_loc, (r+1)*E_loc)
+def _layout(idx, first, n_local: int, tm: int):
+    """Where each routed (token, slot) pair lands in the grouped buffer of
+    this rank's experts ``[first, first + n_local)``.
+
+    Groups (local experts) start on ``tm``-row tile boundaries, are padded
+    with zero rows to whole tiles and own at least one tile each; pairs
+    routed elsewhere land nowhere.  Returns ``local`` (T, K), ``pos`` (T, K)
+    (the row of each local pair; clipped for the others), ``row_pair``
+    (M,) (the pair of each row; T*K for a padding row), ``tile_group``
+    (M // tm,), ``n_tiles`` (1,) and the padded ``sizes`` (n_local,)."""
+    T, K = idx.shape
+    TK = T * K
+    M = -(-TK // tm) * tm + n_local * tm  # every pair local, every group padded
+    local = (idx >= first) & (idx < first + n_local)
+    g = jnp.where(local, idx - first, n_local).reshape(TK)
+    onehot = jax.nn.one_hot(g, n_local + 1, dtype=jnp.int32)
+    rank = (jnp.cumsum(onehot, axis=0) * onehot).sum(-1) - 1  # within the group
+    counts = onehot.sum(0)[:n_local]
+    sizes = jnp.maximum(-(-counts // tm), 1) * tm
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    pos = jnp.where(g < n_local, starts[jnp.minimum(g, n_local - 1)] + rank, M)
+    row_pair = jnp.full((M + 1,), TK, jnp.int32).at[pos].set(
+        jnp.arange(TK, dtype=jnp.int32))[:M]
+    tiles = jnp.arange(M // tm, dtype=jnp.int32)
+    tile_group = jnp.minimum(jnp.searchsorted(ends // tm, tiles, side="right"),
+                             n_local - 1).astype(jnp.int32)
+    n_tiles = (ends[-1:] // tm).astype(jnp.int32)
+    return local, jnp.minimum(pos, M - 1).reshape(T, K), row_pair, tile_group, n_tiles, sizes
+
+
+@jax.custom_vjp
+def _dispatch(x, row_tok, pos, local):
+    """Rows of the grouped buffer: x[row_tok], a zero row where row_tok == T.
+    Its vjp gathers each token's K rows back (no scatter)."""
+    xe = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)])
+    return xe[row_tok]
+
+
+def _dispatch_fwd(x, row_tok, pos, local):
+    return _dispatch(x, row_tok, pos, local), (pos, local)
+
+
+def _dispatch_bwd(res, g):
+    pos, local = res
+    dx = jnp.where(local[..., None], g[pos], 0).astype(jnp.float32).sum(1)
+    return dx.astype(g.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out, cw, pos, local, row_tok, row_w):
+    """y[t] = sum over local slots k of cw[t, k] * out[pos[t, k]] (float32
+    accumulation); rows no local pair points at are never read.  Its vjp
+    gathers too: d out[r] = row_w[r] * dy[row_tok[r]]."""
+    rows = jnp.where(local[..., None], out[pos], 0).astype(jnp.float32)
+    return jnp.einsum("tkd,tk->td", rows, cw).astype(out.dtype)
+
+
+def _combine_fwd(out, cw, pos, local, row_tok, row_w):
+    return _combine(out, cw, pos, local, row_tok, row_w), (out, cw, pos, local,
+                                                         row_tok, row_w)
+
+
+def _combine_bwd(res, dy):
+    out, cw, pos, local, row_tok, row_w = res
+    dye = jnp.concatenate([dy, jnp.zeros((1, dy.shape[1]), dy.dtype)]).astype(jnp.float32)
+    d_out = (dye[row_tok] * row_w[:, None]).astype(out.dtype)
+    rows = jnp.where(local[..., None], out[pos], 0).astype(jnp.float32)
+    d_cw = jnp.einsum("tkd,td->tk", rows, dy.astype(jnp.float32))
+    return d_out, d_cw, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def moe_part(cfg, p, xf, first, n_local: int, *, impl: str = "reference"):
+    """What the experts ``[first, first + n_local)`` give each token: xf
+    (T, D) routed over all experts, the (token, slot) pairs routed to these
+    experts grouped by expert (``_layout``), run through SwiGLU as grouped
+    matrix products (the Pallas ``moe_gmm`` kernel under ``impl="pallas"``,
+    ``jax.lax.ragged_dot`` otherwise), weighted and summed back per token.
+    ``p``'s expert weights are these experts' (n_local, ...).  No pair is
+    dropped: the buffer holds every pair even if all come to these experts."""
+    T = xf.shape[0]
+    K = cfg.top_k
+    tm = GMM_TILE if impl == "pallas" else 1
+    with jax.named_scope("moe_route"):
+        w, idx = _route(cfg, p, xf)
+        local, pos, row_pair, tile_group, n_tiles, sizes = _layout(idx, first, n_local, tm)
+        cw = jnp.where(local, w, 0.0)
+        row_tok = row_pair // K  # T for a padding row
+        row_w = jnp.concatenate([cw.reshape(T * K), jnp.zeros((1,), cw.dtype)])[row_pair]
+        rows = _dispatch(xf, row_tok, pos, local)
+
+    with jax.named_scope("moe_experts"):
+        if impl == "pallas":
+            from repro.kernels import ops as kops
+
+            mm = lambda a, wt: kops.moe_gmm(a, wt, tile_group, n_tiles, tm=tm)
+        else:
+            mm = lambda a, wt: jax.lax.ragged_dot(a, wt, sizes)
+        h = jax.nn.silu(mm(rows, p["wg"])) * mm(rows, p["wu"])
+        out = mm(h, p["wo"])
+
+    with jax.named_scope("moe_combine"):
+        return _combine(out, cw, pos, local, row_tok, row_w)
+
+
+def moe_fwd(cfg, ctx: ParallelCtx, p, x, *, impl: str = "reference"):
+    """Dropless top-k MoE.  x: (B, S, D), replicated across the expert axis.
+
+    Each rank routes every token over all experts and computes the part
+    its own experts give (``moe_part``); one psum over the expert axis adds
+    the ranks' parts, and a shared expert is added once."""
+    B, S, D = x.shape
     ep = ctx.ep_size if ctx.ep_axis else 1
-    E_loc = E // ep
+    E_loc = cfg.experts // ep
     first = (jax.lax.axis_index(ctx.ep_axis) if ctx.ep_axis else 0) * E_loc
-    local = (eid >= first) & (eid < first + E_loc) & keep
-    slot = jnp.where(local, (eid - first) * C + pos, E_loc * C)  # overflow slot
-
-    tok = jnp.arange(T * K) // K
-    buf = jnp.zeros((E_loc * C + 1, D), x.dtype).at[slot].set(xf[tok])
-    ein = buf[: E_loc * C].reshape(E_loc, C, D)
-
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", ein, p["wg"])) * jnp.einsum(
-        "ecd,edf->ecf", ein, p["wu"]
-    )
-    eout = jnp.einsum("ecf,efd->ecd", h, p["wo"])  # (E_loc, C, D)
-
-    flat = jnp.concatenate([eout.reshape(E_loc * C, D), jnp.zeros((1, D), x.dtype)])
-    contrib = flat[slot] * (wflat * local.astype(wflat.dtype))[:, None]
-    y = jnp.zeros((T, D), x.dtype).at[tok].add(contrib)  # partial over expert axis
+    y = moe_part(cfg, p, x.reshape(B * S, D), first, E_loc, impl=impl)
     if ctx.ep_axis:
-        y = jax.lax.psum(y, ctx.ep_axis)
+        with jax.named_scope("moe_combine"):
+            y = jax.lax.psum(y, ctx.ep_axis)
     y = y.reshape(B, S, D)
     if "shared" in p:
         # shared expert is column/row TP-sharded like a dense MLP: its output
-        # is a partial sum and needs its own reduction (this exact missing
-        # psum was caught by the verifier — see EXPERIMENTS.md §Bugs)
+        # is a partial sum and needs its own reduction (covered by
+        # tests/test_granite_moe.py::test_expert_shares_sum_to_whole_layer)
         y = y + ctx.psum_tp(_shared_fwd(cfg, p["shared"], x))
     if ctx.sp and ctx.tp_axis:
         # under SP the caller expects a sequence-sharded activation; y is
@@ -137,13 +228,7 @@ def _dense_router_weights(cfg, p, xf):
     T = xf.shape[0]
     E = cfg.experts
     K = cfg.top_k
-    logits = (xf.astype(jnp.float32) @ p["router"]["w"]).astype(jnp.float32)
-    if cfg.n_experts_padded and cfg.n_experts_padded > cfg.n_experts:
-        pad_mask = jnp.arange(E) >= cfg.n_experts
-        logits = jnp.where(pad_mask[None, :], -1e30, logits)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = jax.lax.top_k(probs, K)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    w, idx = _route(cfg, p, xf)
     dense_w = jnp.zeros((T, E), jnp.float32)
     tok = jnp.arange(T)[:, None].repeat(K, 1)
     return dense_w.at[tok.reshape(-1), idx.reshape(-1)].add(w.reshape(-1))
@@ -151,11 +236,11 @@ def _dense_router_weights(cfg, p, xf):
 
 def moe_dense_fwd(cfg, ctx: ParallelCtx, p, x):
     """Dense-masked MoE formulation: every expert computes every token and a
-    top-k weight mask combines them.  Numerically equals capacity-MoE with
-    infinite capacity; cost O(E/topk) higher — used for the *verification*
-    graphs (static dataflow: all ops are einsums over the expert dim, TP
-    shards the expert FFN width, one psum discharges).  The execution path
-    stays the capacity dispatch (moe_fwd)."""
+    top-k weight mask combines them.  Numerically equals the dropless MoE;
+    cost O(E/topk) higher — used for the *verification* graphs (static
+    dataflow: all ops are einsums over the expert dim, TP shards the expert
+    FFN width, one psum discharges).  The execution path is the dropless
+    grouped MoE (moe_fwd)."""
     B, S, D = x.shape
     T = B * S
     xf = x.reshape(T, D)

@@ -24,13 +24,14 @@ from repro.parallel.ctx import ParallelCtx
 
 from .attention import attn_decode, attn_fwd, attn_init, attn_init_cache
 from .mlp import mlp_fwd, mlp_init, moe_dense_fwd, moe_ep_fwd, moe_fwd, moe_init
-
-# moe_impl -> forward implementation: "capacity" is the execution dispatch,
-# "dense" the TP verification formulation, "ep" the expert-parallel
-# verification formulation (unrolled expert slice/add loop)
-MOE_IMPLS = {"capacity": moe_fwd, "dense": moe_dense_fwd, "ep": moe_ep_fwd}
 from .modules import _init, linear, linear_init, rmsnorm, rmsnorm_init
 from .ssm import ssm_decode, ssm_fwd, ssm_init, ssm_init_cache
+
+# moe_impl -> the verifier's forward formulations: "dense" for TP, "ep" for
+# expert parallelism (unrolled expert slice/add loop); "dropless", the
+# default, is the execution MoE (moe_fwd, grouped matmuls over the experts a
+# rank holds)
+MOE_IMPLS = {"dense": moe_dense_fwd, "ep": moe_ep_fwd}
 
 
 def _tree_index(tree, i):
@@ -39,12 +40,12 @@ def _tree_index(tree, i):
 
 class Model:
     def __init__(self, cfg: ArchConfig, ctx: ParallelCtx = ParallelCtx.single(),
-                 impl: str = "reference", moe_impl: str = "capacity",
+                 impl: str = "reference", moe_impl: str = "dropless",
                  weight_gather=None):
         self.cfg = cfg
         self.ctx = ctx
         self.impl = impl
-        self.moe_impl = moe_impl  # "capacity" (execution) | "dense" (verification)
+        self.moe_impl = moe_impl  # "dropless" (execution) | "dense" | "ep" (verification)
         # weight_gather: tuple over block positions of pytrees of gather dims
         # (-1 = resident). 2D-sharded weights (model x data) are re-gathered
         # over the data axis per block inside the layer scan — bounds resident
@@ -139,6 +140,11 @@ class Model:
         return x
 
     def _embed_tokens(self, params, ids, allow_sp: bool = True):
+        x = self._lookup(params, ids, allow_sp)
+        mult = self.cfg.embedding_multiplier
+        return x if mult == 1.0 else x * jnp.asarray(mult, x.dtype)
+
+    def _lookup(self, params, ids, allow_sp: bool):
         if self.ctx.tp_axis:
             if not allow_sp and self.ctx.sp:
                 from repro.parallel.collectives import vp_embed
@@ -146,13 +152,25 @@ class Model:
                 with jax.named_scope("vp_embed"):
                     return vp_embed(params["embed"]["w"], ids, self.ctx.tp_axis)
             return self._vp_embed(params["embed"]["w"], ids)
-        x = jnp.take(params["embed"]["w"], ids, axis=0)
-        return x
+        return jnp.take(params["embed"]["w"], ids, axis=0)
 
     def _head(self, params, x):
-        """LM head: column-parallel over vocab -> logits (B, S, V_loc)."""
+        """LM head: column-parallel over vocab -> logits (B, S, V_loc),
+        divided by ``logits_scaling``."""
         w = params["embed"]["w"].T if self.cfg.tie_embeddings else params["lm_head"]["w"]
-        return x @ w
+        logits = x @ w
+        scale = self.cfg.logits_scaling
+        return logits if scale == 1.0 else logits / jnp.asarray(scale, logits.dtype)
+
+    def _residual(self, x, branch):
+        """x + branch, the branch scaled by ``residual_multiplier``."""
+        mult = self.cfg.residual_multiplier
+        return x + (branch if mult == 1.0 else branch * jnp.asarray(mult, branch.dtype))
+
+    def _moe(self, p, x):
+        if self.moe_impl == "dropless":
+            return moe_fwd(self.cfg, self.ctx, p, x, impl=self.impl)
+        return MOE_IMPLS[self.moe_impl](self.cfg, self.ctx, p, x)
 
     # ------------------------------------------------------------------ layers
     def _layer_fwd(self, lparams, x, positions, j: int, unroll: bool = False):
@@ -164,15 +182,15 @@ class Model:
                            unroll=unroll)
         else:
             mix = ssm_fwd(cfg, ctx, lparams["ssm"], hn, impl=self.impl, unroll=unroll)
-        x = x + mix
+        x = self._residual(x, mix)
         if "ln2" in lparams:
             h = ctx.sp_exit(x)
             hn = rmsnorm(lparams["ln2"], h, cfg.norm_eps)
             if cfg.is_moe_layer(j):
-                y = MOE_IMPLS[self.moe_impl](cfg, ctx, lparams["moe"], hn)
+                y = self._moe(lparams["moe"], hn)
             else:
                 y = mlp_fwd(cfg, ctx, lparams["mlp"], hn)
-            x = x + y
+            x = self._residual(x, y)
         return x
 
     def forward(self, params, batch, *, unroll: bool = False, remat: bool = False):
@@ -268,15 +286,15 @@ class Model:
                                             bcache[j], position, unroll=unroll)
                   else:
                       mix, nc = ssm_decode(cfg, ctx, bparams[j]["ssm"], hn, bcache[j])
-                  h = h + mix
+                  h = self._residual(h, mix)
                   new_caches.append(nc)
                   if "ln2" in bparams[j]:
                       hn = rmsnorm(bparams[j]["ln2"], h, cfg.norm_eps)
                       if cfg.is_moe_layer(j):
-                          y = MOE_IMPLS[self.moe_impl](cfg, ctx, bparams[j]["moe"], hn)
+                          y = self._moe(bparams[j]["moe"], hn)
                       else:
                           y = mlp_fwd(cfg, ctx, bparams[j]["mlp"], hn)
-                      h = h + y
+                      h = self._residual(h, y)
             return h, tuple(new_caches)
 
         if unroll:

@@ -49,20 +49,37 @@ def adamw_init(params) -> dict:
     }
 
 
-def global_norm(tree) -> jnp.ndarray:
-    leaves = jax.tree_util.tree_leaves(tree)
-    return jnp.sqrt(sum(jnp.sum(jnp.square(leaf.astype(jnp.float32))) for leaf in leaves))
+def _sum_sq(sq: list, tp_sharded=None, tp_axis=None):
+    """The sum of per-leaf squared norms.  Inside shard_map, ``tp_sharded``
+    (a pytree of bools) marks the leaves sharded over ``tp_axis``: their
+    local parts are summed over it, the replicated leaves counted once."""
+    if tp_axis is None:
+        return sum(sq)
+    flags = jax.tree_util.tree_leaves(tp_sharded)
+    split = sum((x for x, f in zip(sq, flags) if f), jnp.float32(0.0))
+    rest = sum((x for x, f in zip(sq, flags) if not f), jnp.float32(0.0))
+    return jax.lax.psum(split, tp_axis) + rest
 
 
-def adamw_update(cfg: AdamWConfig, params, grads, state, *, extra_norm_sq=None):
-    """One AdamW step.  Grads are assumed already averaged across DP.
+def global_norm(tree, tp_sharded=None, tp_axis=None) -> jnp.ndarray:
+    """The gradient's norm (``_sum_sq`` for the TP arguments)."""
+    sq = [jnp.sum(jnp.square(leaf.astype(jnp.float32)))
+          for leaf in jax.tree_util.tree_leaves(tree)]
+    return jnp.sqrt(_sum_sq(sq, tp_sharded, tp_axis))
+
+
+def adamw_update(cfg: AdamWConfig, params, grads, state, *, tp_sharded=None,
+                 tp_axis=None):
+    """One AdamW step.  Grads are assumed already averaged across DP; under
+    tensor parallelism ``tp_sharded``/``tp_axis`` give the norm's shards
+    (``_sum_sq``).
 
     Fault tolerance: a non-finite gradient norm (overflow/NaN from a bad
     batch or a flipped bit) zeroes the update for the whole step instead of
     corrupting parameters — the standard skip-step guard."""
     step = state["step"] + 1
     lr = lr_schedule(cfg, step)
-    gnorm = global_norm(grads)
+    gnorm = global_norm(grads, tp_sharded, tp_axis)
     ok = jnp.isfinite(gnorm)  # skip-step guard: NaN/inf grads leave state as-is
     scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
 
@@ -130,10 +147,11 @@ def zero1_shard_flags(params, dp: int):
 
 
 def zero1_update(cfg: AdamWConfig, params, grads, state, dp_axis, dp: int,
-                 shard_flags=None):
+                 shard_flags=None, tp_sharded=None, tp_axis=None):
     """ZeRO-1 step inside shard_map: reduce_scatter grads over dp, update the
     local parameter shard, all_gather updated shards.  ``shard_flags`` is a
-    pytree of shard dims per leaf (-1 = replicated moments)."""
+    pytree of shard dims per leaf (-1 = replicated moments); ``tp_sharded``
+    and ``tp_axis`` as for ``_sum_sq``."""
     step = state["step"] + 1
     lr = lr_schedule(cfg, step)
     b1, b2 = cfg.b1, cfg.b2
@@ -163,11 +181,9 @@ def zero1_update(cfg: AdamWConfig, params, grads, state, dp_axis, dp: int,
         gsh_all.append(gsh)
 
     # global grad norm from the scattered shards (replicated leaves counted once)
-    local_sq = sum(
-        jnp.sum(jnp.square(g)) if dim >= 0 else jnp.sum(jnp.square(g)) / dp
-        for g, dim in zip(gsh_all, flat_dims)
-    )
-    gnorm = jnp.sqrt(jax.lax.psum(local_sq, dp_axis))
+    sq = [jnp.sum(jnp.square(g)) if dim >= 0 else jnp.sum(jnp.square(g)) / dp
+          for g, dim in zip(gsh_all, flat_dims)]
+    gnorm = jnp.sqrt(jax.lax.psum(_sum_sq(sq, tp_sharded, tp_axis), dp_axis))
     scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
     scale = jnp.where(jnp.isfinite(gnorm), scale, 0.0)  # skip-step guard
 

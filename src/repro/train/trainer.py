@@ -14,6 +14,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro.models import Model
 
@@ -62,15 +63,40 @@ def local_grads(model: Model, tcfg: TrainConfig, params, batch):
     return loss_sum * inv, grads
 
 
+def tp_sharded_leaves(params, tp_axis: str):
+    """Pytree of bools: which leaves ``param_specs`` shards over ``tp_axis``."""
+    from repro.parallel.sharding import param_specs
+
+    return jax.tree_util.tree_map(lambda s: tp_axis in tuple(s),
+                                  param_specs(params, tp_axis),
+                                  is_leaf=lambda s: isinstance(s, P))
+
+
+def tp_reduce_grads(grads, tp_sharded, tp_axis: str, tp_size: int):
+    """The gradients of the loss from those shard_map gives without
+    replication checks (``check_vma=False``), where every rank's equal loss
+    counts as an output of its own: they are the gradients of the sum of the
+    ``tp_size`` losses, and a replicated leaf's copy holds only what its own
+    rank's work contributes.  Divide by ``tp_size``, and sum the copies of a
+    replicated leaf over ``tp_axis``."""
+    return jax.tree_util.tree_map(
+        lambda g, f: (g if f else lax.psum(g, tp_axis)) / tp_size, grads, tp_sharded)
+
+
 def make_step_fn(model: Model, tcfg: TrainConfig, shard_flags=None):
-    """The per-device train step (to be wrapped in shard_map by the caller).
+    """The per-device train step (to be wrapped in shard_map by the caller,
+    with ``check_vma=False``).
 
     signature: (params, opt_state, batch) -> (params, opt_state, metrics)
     """
     ctx = model.ctx
+    tp = ctx.tp_axis if ctx.tp_axis and ctx.tp_size > 1 else None
 
     def step(params, opt_state, batch):
         loss, grads = local_grads(model, tcfg, params, batch)
+        split = tp_sharded_leaves(params, tp) if tp else None
+        if tp:
+            grads = tp_reduce_grads(grads, split, tp, ctx.tp_size)
         if ctx.dp_axis:
             loss = lax.pmean(loss, ctx.dp_axis)
         if tcfg.zero1 and ctx.dp_axis:
@@ -82,14 +108,15 @@ def make_step_fn(model: Model, tcfg: TrainConfig, shard_flags=None):
             with jax.named_scope("optimizer"):
                 new_p, new_s, info = zero1_update(
                     tcfg.opt, params, grads, opt_state, scatter_axis, sizes[-1],
-                    shard_flags)
+                    shard_flags, tp_sharded=split, tp_axis=tp)
         else:
             if ctx.dp_axis:
                 grads = allreduce_compressed(grads, tcfg.grad_compress, ctx.dp_axis)
                 grads = jax.tree_util.tree_map(lambda g: g / ctx.dp_size, grads)
             with jax.named_scope("optimizer"):
                 new_p, new_s, info = adamw_update(tcfg.opt, params, grads,
-                                                  opt_state)
+                                                  opt_state, tp_sharded=split,
+                                                  tp_axis=tp)
         metrics = {"loss": loss, **info}
         return new_p, new_s, metrics
 

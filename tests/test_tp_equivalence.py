@@ -201,3 +201,52 @@ CP_TEMPLATE = _PRELUDE + textwrap.dedent("""
 
 def test_context_parallel_flash_decode():
     _run(CP_TEMPLATE)
+
+
+GRAD_TEMPLATE = _PRELUDE + textwrap.dedent("""
+    from repro.train.trainer import TrainConfig, make_step_fn
+    from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
+
+    cfg, mesh, ctx, md, ms, params, batch, pspecs, bspecs = tp_forward("{arch}")
+    tcfg = TrainConfig(opt=AdamWConfig(lr=1e-3, warmup_steps=1), remat=False,
+                       zero1={zero1})
+    grads = jax.grad(lambda p: ms.loss(p, batch))(params)
+    _, ref, _ = adamw_update(tcfg.opt, params, grads, adamw_init(params))
+    if {zero1}:
+        from repro.launch.dryrun import _zero_flags_from_specs, _opt_specs
+        flags = _zero_flags_from_specs(jax.eval_shape(lambda: params), 4, pspecs)
+        step = make_step_fn(md, tcfg, shard_flags=flags)
+        opt = {{"m": jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
+               "v": jax.tree_util.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), params),
+               "step": jnp.zeros((), jnp.int32)}}
+        ospecs = _opt_specs(pspecs, zero1=True, dp_last="data", flags=flags)
+    else:
+        step = make_step_fn(md, tcfg)
+        opt = adamw_init(params)
+        ospecs = {{"m": pspecs, "v": pspecs, "step": P()}}
+    from jax import shard_map
+    fn = shard_map(step, mesh=mesh, in_specs=(pspecs, ospecs, bspecs),
+                   out_specs=(pspecs, ospecs, {{"loss": P(), "grad_norm": P(), "lr": P()}}),
+                   check_vma=False)
+    with mesh:
+        _, newopt, metrics = jax.jit(fn)(params, opt, batch)
+    gn = float(jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree_util.tree_leaves(grads))))
+    assert abs(float(metrics["grad_norm"]) - gn) <= 1e-4 * gn, (metrics["grad_norm"], gn)
+    for (k, a), b in zip(jax.tree_util.tree_flatten_with_path(ref["m"])[0],
+                         jax.tree_util.tree_leaves(newopt["m"])):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_allclose(b, a, rtol=1e-4, atol=1e-4 * np.abs(a).max(),
+                                   err_msg=jax.tree_util.keystr(k))
+    print("OK")
+""")
+
+
+@pytest.mark.parametrize("arch,zero1", [("qwen3_4b", False), ("granite_moe_3b", False),
+                                        ("qwen3_4b", True)])
+def test_tp_gradients_match_single_device(arch, zero1):
+    """The TP step's gradient, as its first AdamW moment and its global norm,
+    equals the single-device one leaf by leaf: sharded leaves are not
+    counted once per rank, and replicated leaves (norms, router) sum every
+    rank's share.  AdamW's update is nearly scale-free, so the parameter
+    checks above cannot see this."""
+    _run(GRAD_TEMPLATE.format(arch=arch, zero1=zero1))

@@ -76,3 +76,31 @@ def test_rmsnorm_compiles_qwen3_4b(spec, rows):
     d = get_config("qwen3_4b").d_model
     _compiles_to_kernel(lambda x, s: rn.rmsnorm(x, s), spec((rows, d), BF16),
                         spec((d,), BF16))
+
+
+@pytest.mark.parametrize("which", ["gate", "down", "grad_lhs", "grad_w"])
+def test_moe_gmm_compiles_granite_moe_3b(spec, which):
+    """The grouped expert matmuls of one TP-4 rank of granite_moe_3b: 10 of 40
+    experts, one 4096-token row routed top-8."""
+    from repro.kernels import moe_gmm
+    from repro.models.mlp import GMM_TILE as tm
+
+    cfg = get_config("granite_moe_3b")
+    E, D, F = cfg.n_experts // 4, cfg.d_model, cfg.d_ff_expert
+    m = 4096 * cfg.top_k + E * tm
+    grp, nt = spec((m // tm,), jnp.int32), spec((1,), jnp.int32)
+    if which == "gate":
+        fn = lambda a, w, g, n: moe_gmm.gmm(a, w, g, n, tm=tm)
+        args = (spec((m, D), BF16), spec((E, D, F), BF16))
+    elif which == "down":
+        fn = lambda a, w, g, n: moe_gmm.gmm(a, w, g, n, tm=tm)
+        args = (spec((m, F), BF16), spec((E, F, D), BF16))
+    elif which == "grad_lhs":
+        fn = lambda a, w, g, n: moe_gmm.gmm(a, w, g, n, tm=tm, transpose_rhs=True)
+        args = (spec((m, F), BF16), spec((E, D, F), BF16))
+    else:
+        fn = lambda a, b, g, n: moe_gmm.tgmm(a, b, g, n, groups=E, tm=tm)
+        args = (spec((m, D), BF16), spec((m, F), BF16))
+    text = jax.jit(fn).lower(*args, grp, nt).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert ("moe_tgmm" if which == "grad_w" else "moe_gmm") in text
