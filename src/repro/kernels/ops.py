@@ -21,8 +21,8 @@ from . import ssd_scan as _ssd
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret",
                                    "scale"))
-def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = False,
+def flash_attention(q, k, v, *, causal: bool = True, block_q: int | None = None,
+                    block_k: int | None = None, interpret: bool = False,
                     scale: float | None = None):
     return _fa.flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, interpret=interpret, scale=scale)

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops
 from repro.kernels.ref import attention_ref, rmsnorm_ref, ssd_ref
 from repro.models.attention import chunked_attention
@@ -17,27 +18,56 @@ def _tol(dtype):
     return dict(rtol=5e-2, atol=5e-2) if dtype == jnp.bfloat16 else dict(rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("B,Hq,Hkv,Sq,Sk,D", [
-    (1, 2, 2, 128, 128, 64),     # MHA square
-    (2, 4, 2, 128, 128, 64),     # GQA
-    (1, 4, 1, 64, 256, 64),      # MQA, cross lengths
-    (1, 2, 2, 256, 256, 128),    # head_dim 128
-    (1, 8, 2, 96, 160, 32),      # non-multiple of block
+def _case(B, Hq, Hkv, Sq, Sk, D, bq=64, bk=64, id=None):
+    return pytest.param(B, Hq, Hkv, Sq, Sk, D, bq, bk,
+                        id=id or f"{B}-{Hq}-{Hkv}-{Sq}-{Sk}-{D}")
+
+
+@pytest.mark.parametrize("B,Hq,Hkv,Sq,Sk,D,bq,bk", [
+    _case(1, 2, 2, 128, 128, 64),     # MHA square
+    _case(2, 4, 2, 128, 128, 64),     # GQA
+    _case(1, 4, 1, 64, 256, 64),      # MQA, cross lengths
+    _case(1, 2, 2, 256, 256, 128),    # head_dim 128
+    _case(1, 8, 2, 96, 160, 32),      # non-multiple of block
+    # bq != bk: the diagonal crosses blocks inside and runs along their edges
+    _case(1, 2, 1, 384, 384, 64, 128, 64, id="bq128-bk64"),
+    _case(1, 2, 1, 384, 384, 64, 64, 128, id="bq64-bk128"),
+    _case(1, 2, 2, 200, 200, 64, id="kv-tail"),  # last key block padded 56 of 64
+    # the four-chip Granite cell's heads a chip (6 query, 2 kv, head_dim 64),
+    # tiles from the shape: two 512-row blocks a side, three pairs visited
+    _case(1, 6, 2, 1024, 1024, 64, None, None, id="gqa-6-2-shape-tiles"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_vs_ref(B, Hq, Hkv, Sq, Sk, D, dtype, causal):
+def test_flash_attention_vs_ref(B, Hq, Hkv, Sq, Sk, D, bq, bk, dtype, causal):
     if causal and Sq != Sk:
         pytest.skip("causal offset semantics only tested square here")
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, Hq, Sq, D), dtype)
     k = jax.random.normal(ks[1], (B, Hkv, Sk, D), dtype)
     v = jax.random.normal(ks[2], (B, Hkv, Sk, D), dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+    out = ops.flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
                               interpret=True)
     ref = attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("bq,bk", [(None, None), (128, 128), (256, 128), (128, 512)])
+def test_flash_attention_visited_blocks(bq, bk, causal):
+    """The causal schedule's count: at 4096 tokens, the block pairs on or under
+    the diagonal (every pair without ``causal``)."""
+    S = 4096
+    if bq is None:
+        bq, bk = fa.block_sizes(S, S)
+        assert (bq, bk) == (512, 512)
+    n_q, n_k = S // bq, S // bk
+    lower = sum(ik * bk <= iq * bq + bq - 1 for iq in range(n_q) for ik in range(n_k))
+    if bq == bk:
+        assert lower == n_q * (n_q + 1) // 2
+    assert fa.visited_blocks(S, S, bq, bk, causal) == (
+        lower if causal else n_q * n_k, n_q * n_k)
 
 
 def test_flash_attention_matches_chunked_jnp():

@@ -1,8 +1,9 @@
 """The Pallas kernels compile with Mosaic for a described TPU v5e at the
 widths the chip smoke runs them: flash attention at qwen3_4b (prefill and
-decode), the SSD scan at mamba2_130m, RMSNorm at qwen3_4b.  Nothing runs —
-this catches what interpret mode accepts and the chip's compiler refuses
-(block shapes off the (8, 128) tiling, too much VMEM)."""
+decode) and at one chip's heads of the four-chip Granite training step, the
+SSD scan at mamba2_130m, RMSNorm at qwen3_4b.  Nothing runs — this catches
+what interpret mode accepts and the chip's compiler refuses (block shapes off
+the (8, 128) tiling, too much VMEM)."""
 import os
 
 import jax
@@ -50,14 +51,19 @@ def _compiles_to_kernel(fn, *args):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("sq,sk,causal", [(2048, 2048, True), (1, 512, False)],
-                         ids=["prefill", "decode"])
-def test_flash_attention_compiles_qwen3_4b(spec, sq, sk, causal):
-    cfg = get_config("qwen3_4b")
-    q = spec((1, cfg.n_heads, sq, cfg.hd), BF16)
-    kv = spec((1, cfg.n_kv_heads, sk, cfg.hd), BF16)
-    _compiles_to_kernel(lambda q, k, v: fa.flash_attention(q, k, v, causal=causal),
-                        q, kv, kv)
+@pytest.mark.parametrize("arch,tp,sq,sk,causal", [
+    ("qwen3_4b", 1, 2048, 2048, True), ("qwen3_4b", 1, 1, 512, False),
+    ("granite_moe_3b", 4, 4096, 4096, True),
+], ids=["prefill", "decode", "granite_train_tp4"])
+def test_flash_attention_compiles_qwen3_4b(spec, arch, tp, sq, sk, causal):
+    """Tiles from the shape; granite_train_tp4 is one chip's heads of the
+    four-chip Granite training step (6 query, 2 kv, head_dim 64), whose causal
+    schedule clamps the k/v index maps at 512-row tiles."""
+    cfg = get_config(arch)
+    q = spec((1, cfg.n_heads // tp, sq, cfg.hd), BF16)
+    kv = spec((1, cfg.n_kv_heads // tp, sk, cfg.hd), BF16)
+    _compiles_to_kernel(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, scale=cfg.attn_scale), q, kv, kv)
 
 
 @pytest.mark.parametrize("heads", ["published", "padded"])
